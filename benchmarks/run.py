@@ -6,7 +6,8 @@ directory, so the perf trajectory is recorded run over run (build
 throughput, bytes/query, q/s — see benchmarks/jax_bench.py).
 
 Set ``REPRO_BENCH_TINY=1`` to run every bench at smoke sizes (used by the
-CI bench-smoke job to keep the JSON plumbing honest).
+CI bench-smoke job to keep the JSON plumbing honest).  A bench that
+raises is recorded as an ``ERROR`` row and the harness exits non-zero.
 """
 
 from __future__ import annotations
@@ -19,12 +20,15 @@ import time
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def main() -> None:
+def main() -> int:
     # Anchor on the repo root so the harness runs the same from any CWD
     # (`python benchmarks/run.py`, `python -m benchmarks.run`, CI).
     for p in (_ROOT, os.path.join(_ROOT, "src")):
         if p not in sys.path:
             sys.path.insert(0, p)
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks.tables import TABLES
     from benchmarks.jax_bench import JAX_BENCHES
 
@@ -52,7 +56,12 @@ def main() -> None:
         json.dump({"date": date, "rows": rows}, f, indent=1, default=float)
         f.write("\n")
     print(f"# wrote {path} ({len(rows)} rows)", file=sys.stderr)
+    errors = [r["name"] for r in rows if r["us_per_call"] < 0]
+    if errors:
+        print(f"# {len(errors)} bench(es) raised: {errors}", file=sys.stderr)
+        return 1
+    return 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

@@ -332,8 +332,11 @@ class BuildArtifacts:
         self._quantized = None
         self._quantized8 = None
         # Autotuned TileConfig winners keyed by kernels.autotune.shape_key,
-        # shared by every backend over these artifacts (DESIGN.md §12).
+        # shared by every backend over these artifacts (DESIGN.md §12),
+        # and the candidates the tuner refused: (shape_key, TileConfig)
+        # -> first error.
         self.tuned: dict = {}
+        self.tune_refusals: dict = {}
         if structure == "mqr":
             _reject_opts(structure, levels=levels, max_entries=max_entries,
                          build=build)
@@ -399,6 +402,7 @@ class BuildArtifacts:
         self._quantized = quantized
         self._quantized8 = None
         self.tuned = {}
+        self.tune_refusals = {}
         if structure == "mqr":
             self.pointer_tree = mqrtree.build(self.mbrs)
         elif structure == "rtree":

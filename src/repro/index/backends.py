@@ -264,6 +264,7 @@ class PallasBackend:
         self._tuned = getattr(artifacts, "tuned", None)
         if self._tuned is None:
             self._tuned = {}
+        self._refusals = getattr(artifacts, "tune_refusals", {})
 
     def _config(self, queries: np.ndarray):
         from repro.kernels.autotune import (
@@ -298,10 +299,12 @@ class PallasBackend:
             cands = candidates(
                 width, nq, precision=self.precision, stream=self.stream
             )
-            cfg, _ = tune(
+            cfg, _, refused = tune(
                 lambda c: lambda: np.asarray(self._run(probe, c)[0]), cands
             )
             self._tuned[key] = cfg
+            for c, err in refused.items():
+                self._refusals[(key, c)] = err
         return cfg
 
     def _run_one(self, queries: np.ndarray, cfg):

@@ -22,9 +22,11 @@ reuses the measurement instead of re-timing.
 
 Timing is wall-clock over the backend's own runner, after one warm-up
 call (so jit/lowering cost is excluded), best-of-``iters``.  A candidate
-that raises (e.g. a tile shape the runtime rejects) is skipped, never
-fatal.  The fixed default ``TileConfig()`` is always in the candidate
-grid, so the tuned pick can only match or beat it.
+that raises (e.g. a tile shape the runtime rejects) is not timed; it is
+returned among the refusals with its first error, which the caller
+records in ``BuildArtifacts.tune_refusals``.  The fixed default
+``TileConfig()`` is always in the candidate grid, so the tuned pick can
+only match or beat it.
 """
 
 from __future__ import annotations
@@ -41,7 +43,9 @@ __all__ = [
     "tune",
 ]
 
-DEFAULT_BLOCK_WS = (64, 128, 256, 512)
+# Whole multiples of the chip's 128-lane vreg width: Mosaic refuses a
+# narrower tile of the (…, 4, W) level arrays.
+DEFAULT_BLOCK_WS = (128, 256, 512)
 
 # autotune="auto" only spends tuning time when the slot grid is at least
 # this wide; narrower schedules sweep in microseconds at any tile shape.
@@ -105,16 +109,18 @@ def candidates(width: int, n_queries: int, *, precision: str = "float32",
 
 
 def tune(make_run, cands, *, iters: int = 2):
-    """Time every candidate and return ``(best_cfg, {cfg: seconds})``.
+    """Time every candidate; returns ``(best_cfg, {cfg: seconds},
+    {cfg: first error})``.
 
     ``make_run(cfg)`` returns a zero-argument callable executing the
     search under that configuration (the caller blocks on the result so
     the measurement covers real work).  One warm-up call per candidate
     excludes jit/lowering cost; the score is the best of ``iters`` timed
-    calls.  Candidates that raise are skipped; if all do, the fixed
-    default wins by fiat.
+    calls.  A candidate that raises is refused and reported with its
+    error; if all are, the fixed default wins by fiat.
     """
     timings: dict[TileConfig, float] = {}
+    refused: dict[TileConfig, str] = {}
     best = None
     for cfg in cands:
         try:
@@ -123,14 +129,15 @@ def tune(make_run, cands, *, iters: int = 2):
             t = min(
                 _timed(fn) for _ in range(max(iters, 1))
             )
-        except Exception:
+        except Exception as e:  # noqa: BLE001 — reported, not hidden
+            refused[cfg] = f"{type(e).__name__}: {e}"
             continue
         timings[cfg] = t
         if best is None or t < timings[best]:
             best = cfg
     if best is None:
         best = TileConfig()
-    return best, timings
+    return best, timings, refused
 
 
 def _timed(fn) -> float:

@@ -15,11 +15,13 @@ Two engines, bit-identical outputs:
   object MBRs stay VMEM-resident coordinate-major for the whole build; per
   level the kernel (a) subdivides each multi-member group by the
   branch-free Fig. 2 quadrant select of ``bulk.quad_code``, (b) densifies
-  the new ``parent*5+quad`` keys with a presence-mask + prefix-sum rank
-  (identical numbering to ``bulk._densify``'s sort-based ranks, because
-  both assign dense ids in ascending key order), and (c) computes each
-  group's enclosing MBR as a segment min/max over ``block_n``-object tiles
-  (one-hot select + tile reduce).  Group-of / slot-MBR / parent rows are
+  the new ``parent*5+quad`` keys into ascending-key ranks (identical
+  numbering to ``bulk._densify``'s sort-based ranks), and (c) computes
+  each group's enclosing MBR as a segment min/max over ``block_n``-object
+  tiles (one-hot select + tile reduce).  On the TPU (a) and (b) are
+  gather-free compare-select-reduce passes over (128, 128) tiles — Mosaic
+  lowers neither lane gathers nor ``cumsum``; the interpreter keeps the
+  gather + prefix-sum form.  Group-of / slot-MBR / parent rows are
   emitted level by level straight into the schedule layout.
 * ``engine="jnp"`` — ``bulk.build_pyramid`` (the parity oracle) plus a
   vectorized scatter for the parent map, all jit'd; this is also the
@@ -47,6 +49,8 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core import bulk
 from repro.core.flat import LevelSchedule
 
+from .pyramid_scan import COMPILER_PARAMS
+
 # Above this the whole-set VMEM residency of the build kernel stops making
 # sense (objects, bounds, and the 5x key space all live on chip); the
 # ``auto`` engine falls back to the jit'd jnp fixed point.
@@ -55,13 +59,16 @@ PALLAS_BUILD_MAX_N = 4096
 
 def _build_kernel(
     mbr_ref,      # (4, W) f32 — object MBRs coordinate-major, resident
-    gof_ref,      # out (1, W) i32 — group id per object at this level
+    gof_ref,      # out (1, 1, W) i32 — group id per object at this level
     mbr_out_ref,  # out (1, 4, W) f32 — slot MBRs of this level
-    par_out_ref,  # out (1, W) i32 — parent slot of each slot
+    par_out_ref,  # out (1, 1, W) i32 — parent slot of each slot
     gid_ref,      # scratch (1, W) i32 — current-level group ids
     prev_ref,     # scratch (1, W) i32 — previous-level group ids
+    key_ref,      # scratch (1, W) i32 — subdivision keys of this level
+    rank_ref,     # scratch (1, W) f32 — dense ranks of those keys
     bounds_ref,   # scratch (4, W) f32 — per-slot MBRs (segment min/max)
     counts_ref,   # scratch (1, W) f32 — per-slot member counts
+    bcol_ref,     # scratch (5, W, B) f32 — bounds + counts, slot-major
     *,
     n: int,
     width: int,
@@ -69,12 +76,6 @@ def _build_kernel(
     onehot_gather: bool,
 ):
     l = pl.program_id(0)
-    lane = jax.lax.broadcasted_iota(jnp.int32, (1, width), 1)[0]  # (W,)
-    valid = lane < n
-    n_tiles = width // block_n
-
-    cx = (mbr_ref[0, :] + mbr_ref[2, :]) * 0.5  # (W,) object centroids
-    cy = (mbr_ref[1, :] + mbr_ref[3, :]) * 0.5
 
     @pl.when(l == 0)
     def _root():
@@ -84,103 +85,198 @@ def _build_kernel(
     @pl.when(l > 0)
     def _subdivide():
         # Level l-1 state is still in scratch: derive level-l group ids.
-        gid = gid_ref[0, :]
-        # Empty slots carry +/-inf sentinels; members only ever gather
-        # their own (non-empty, finite) group, so zero the empties to keep
-        # 0*inf NaNs out of the one-hot matmul.
-        safe = jnp.where(counts_ref[...] > 0.0, bounds_ref[...], 0.0)
         if onehot_gather:
-            # MXU path: per-object group box/count via one-hot matmuls
-            # over block_n-object tiles.
-            gb_tiles, cnt_tiles = [], []
-            for t in range(n_tiles):
-                sl = slice(t * block_n, (t + 1) * block_n)
-                oh = (
-                    jax.lax.broadcasted_iota(jnp.int32, (block_n, width), 1)
-                    == gid[sl][:, None]
-                ).astype(jnp.float32)
-                gb_tiles.append(
-                    jnp.dot(oh, safe.T, preferred_element_type=jnp.float32).T
-                )
-                cnt_tiles.append(jnp.dot(oh, counts_ref[0, :]))
-            gb = jnp.concatenate(gb_tiles, axis=1)    # (4, W)
-            cnt = jnp.concatenate(cnt_tiles)          # (W,)
+            _subdivide_tiled(mbr_ref, gid_ref, prev_ref, key_ref, rank_ref,
+                             bcol_ref, n=n, width=width, block_n=block_n)
         else:
-            gb = jnp.take(safe, gid, axis=1)          # (4, W)
-            cnt = jnp.take(counts_ref[0, :], gid)     # (W,)
-        gcx = (gb[0] + gb[2]) * 0.5
-        gcy = (gb[1] + gb[3]) * 0.5
-        quad = bulk.quad_code(cx, cy, gcx, gcy)
-        # Same key rule as bulk.build_pyramid: singletons keep their slot
-        # ("quad 0" of their own group); keys stay unique per group.
-        key = jnp.where(cnt > 1.5, gid * 5 + quad, gid * 5)
-        key = jnp.where(valid, key, 0)
-        # Densify: presence mask over the 5W key space, then prefix-sum
-        # ranks — ascending-key numbering, exactly bulk._densify's.
-        kspace = 5 * width
-        pres = jnp.zeros((kspace,), jnp.float32)
-        for t in range(n_tiles):
-            sl = slice(t * block_n, (t + 1) * block_n)
-            oh5 = (
-                jax.lax.broadcasted_iota(jnp.int32, (block_n, kspace), 1)
-                == key[sl][:, None]
-            ) & valid[sl][:, None]
-            pres = jnp.maximum(pres, oh5.astype(jnp.float32).max(axis=0))
-        rank = jnp.cumsum(pres) - 1.0  # (5W,) f32; exact for n < 2**24
-        if onehot_gather:
-            gid_tiles = []
-            for t in range(n_tiles):
-                sl = slice(t * block_n, (t + 1) * block_n)
-                oh5 = (
-                    jax.lax.broadcasted_iota(jnp.int32, (block_n, kspace), 1)
-                    == key[sl][:, None]
-                ).astype(jnp.float32)
-                gid_tiles.append(jnp.dot(oh5, rank).astype(jnp.int32))
-            new_gid = jnp.concatenate(gid_tiles)
-        else:
-            new_gid = jnp.take(rank, key).astype(jnp.int32)
-        prev_ref[...] = gid_ref[...]
-        gid_ref[0, :] = jnp.where(valid, new_gid, 0)
+            _subdivide_gather(mbr_ref, gid_ref, prev_ref, bounds_ref,
+                              counts_ref, n=n, width=width, block_n=block_n)
 
-    # Segment min/max for the CURRENT level's groups, block_n objects at a
-    # time (the "VMEM-resident tiles" of the level fixed point).
-    bounds_ref[0, :] = jnp.full((width,), jnp.inf, jnp.float32)
-    bounds_ref[1, :] = jnp.full((width,), jnp.inf, jnp.float32)
-    bounds_ref[2, :] = jnp.full((width,), -jnp.inf, jnp.float32)
-    bounds_ref[3, :] = jnp.full((width,), -jnp.inf, jnp.float32)
-    counts_ref[...] = jnp.zeros((1, width), jnp.float32)
-    par_acc = jnp.zeros((width,), jnp.float32)
+    _segment_bounds(mbr_ref, gid_ref, prev_ref, bounds_ref, counts_ref,
+                    bcol_ref, par_out_ref, l=l, n=n, width=width,
+                    block_n=block_n)
+    gof_ref[0] = gid_ref[...]
+    mbr_out_ref[0] = bounds_ref[...]
+
+
+def _group_key(mbr, gid, gb, cnt):
+    """Fig. 2 subdivision key of each object: its quadrant about its
+    group's MBR centroid; singletons keep their slot ("quad 0" of their
+    own group), so keys stay unique per group (bulk.build_pyramid)."""
+    cx = (mbr[0] + mbr[2]) * 0.5
+    cy = (mbr[1] + mbr[3]) * 0.5
+    gcx = (gb[0] + gb[2]) * 0.5
+    gcy = (gb[1] + gb[3]) * 0.5
+    quad = bulk.quad_code(cx, cy, gcx, gcy)
+    return jnp.where(cnt > 1.5, gid * 5 + quad, gid * 5)
+
+
+def _subdivide_gather(mbr_ref, gid_ref, prev_ref, bounds_ref, counts_ref, *,
+                      n, width, block_n):
+    """Interpreter path: lane gathers and a cumsum over the 5W key space."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, width), 1)[0]  # (W,)
+    valid = lane < n
+    n_tiles = width // block_n
     gid = gid_ref[0, :]
-    prev = prev_ref[0, :]
+    # Empty slots carry +/-inf sentinels; members only ever gather their
+    # own (non-empty, finite) group.
+    safe = jnp.where(counts_ref[...] > 0.0, bounds_ref[...], 0.0)
+    gb = jnp.take(safe, gid, axis=1)                # (4, W)
+    cnt = jnp.take(counts_ref[0, :], gid)           # (W,)
+    key = _group_key(mbr_ref[...], gid, gb, cnt)
+    key = jnp.where(valid, key, 0)
+    # Densify: presence mask over the 5W key space, then prefix-sum
+    # ranks — ascending-key numbering, exactly bulk._densify's.
+    kspace = 5 * width
+    pres = jnp.zeros((kspace,), jnp.float32)
     for t in range(n_tiles):
         sl = slice(t * block_n, (t + 1) * block_n)
-        oh = (
-            jax.lax.broadcasted_iota(jnp.int32, (block_n, width), 1)
-            == gid[sl][:, None]
+        oh5 = (
+            jax.lax.broadcasted_iota(jnp.int32, (block_n, kspace), 1)
+            == key[sl][:, None]
         ) & valid[sl][:, None]
-        for c, red, fill in ((0, jnp.min, jnp.inf), (1, jnp.min, jnp.inf),
-                             (2, jnp.max, -jnp.inf), (3, jnp.max, -jnp.inf)):
-            part = red(
-                jnp.where(oh, mbr_ref[c, sl][:, None], fill), axis=0
-            )
-            bounds_ref[c, :] = (
-                jnp.minimum(bounds_ref[c, :], part)
-                if red is jnp.min
-                else jnp.maximum(bounds_ref[c, :], part)
-            )
-        counts_ref[0, :] = counts_ref[0, :] + oh.astype(jnp.float32).sum(axis=0)
-        # parent[slot of member] = member's previous-level gid (groups
-        # nest, so every member agrees); max-reduce the (prev+1) tags.
-        par_acc = jnp.maximum(
-            par_acc,
-            jnp.where(oh, (prev[sl] + 1).astype(jnp.float32)[:, None],
-                      0.0).max(axis=0),
-        )
+        pres = jnp.maximum(pres, oh5.astype(jnp.float32).max(axis=0))
+    rank = jnp.cumsum(pres) - 1.0  # (5W,) f32; exact for n < 2**24
+    new_gid = jnp.take(rank, key).astype(jnp.int32)
+    prev_ref[...] = gid_ref[...]
+    gid_ref[0, :] = jnp.where(valid, new_gid, 0)
 
-    gof_ref[0, :] = gid
-    mbr_out_ref[0] = bounds_ref[...]
-    parent = jnp.maximum(par_acc, 1.0).astype(jnp.int32) - 1
-    par_out_ref[0, :] = jnp.where(l > 0, parent, 0)
+
+def _subdivide_tiled(mbr_ref, gid_ref, prev_ref, key_ref, rank_ref, bcol_ref,
+                     *, n, width, block_n):
+    """TPU path: the same subdivision with no gather and no scan.
+
+    Mosaic lowers neither lane gathers nor cumsum, so every lookup is a
+    (B, B) compare-select-reduce over one slot tile × one object tile:
+    each object's group box and count are selected out of the slot-major
+    ``bcol`` copy, and the dense rank of a key is the number of DISTINCT
+    valid keys below it (``bulk._densify``'s ascending-key numbering),
+    counted pairwise.  Keys and ids stay below 2**24, so the float32
+    compares and counts are exact."""
+    b = block_n
+    n_tiles = width // b
+    sub = jax.lax.broadcasted_iota(jnp.int32, (b, b), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (b, b), 1)
+    eye = sub == lane
+
+    def tile(t):
+        return pl.ds(pl.multiple_of(t * b, b), b)
+
+    def key_tile(ot, carry):
+        gid_t = gid_ref[:, tile(ot)]                          # (1, B)
+
+        def gather(gt, acc):
+            own = sub + gt * b == gid_t                       # (slots, objs)
+            return tuple(
+                jnp.maximum(a, jnp.max(
+                    jnp.where(own, bcol_ref[c, tile(gt), :], -jnp.inf),
+                    axis=0, keepdims=True))
+                for c, a in enumerate(acc)
+            )
+
+        init = (jnp.full((1, b), -jnp.inf, jnp.float32),) * 5
+        lox, loy, hix, hiy, cnt = jax.lax.fori_loop(0, n_tiles, gather, init)
+        m = mbr_ref[:, tile(ot)]                              # (4, B)
+        key = _group_key([m[c:c + 1] for c in range(4)], gid_t,
+                         (lox, loy, hix, hiy), cnt)
+        valid = lane[:1] + ot * b < n
+        key_ref[:, tile(ot)] = jnp.where(valid, key, 0)
+        return carry
+
+    jax.lax.fori_loop(0, n_tiles, key_tile, 0)
+
+    rank_ref[...] = jnp.zeros((1, width), jnp.float32)
+
+    def rank_tile(jt, carry):
+        kj = key_ref[:, tile(jt)].astype(jnp.float32)
+        kj = jnp.max(jnp.where(eye, kj, -jnp.inf), axis=1, keepdims=True)
+        j = sub + jt * b                                      # j on sublanes
+
+        def dup_tile(it, dup):
+            i = lane + it * b
+            same = (kj == key_ref[:, tile(it)].astype(jnp.float32)) & (i < j)
+            return jnp.maximum(dup, jnp.max(
+                jnp.where(same & (i < n), 1.0, 0.0), axis=1, keepdims=True))
+
+        dup = jax.lax.fori_loop(0, n_tiles, dup_tile,
+                                jnp.zeros((b, 1), jnp.float32))
+        first = (dup < 0.5) & (j < n)   # j is the first object with its key
+
+        def count_tile(it, c):
+            below = first & (kj < key_ref[:, tile(it)].astype(jnp.float32))
+            rank_ref[:, tile(it)] += jnp.sum(
+                jnp.where(below, 1.0, 0.0), axis=0, keepdims=True)
+            return c
+
+        jax.lax.fori_loop(0, n_tiles, count_tile, 0)
+        return carry
+
+    jax.lax.fori_loop(0, n_tiles, rank_tile, 0)
+    valid = jax.lax.broadcasted_iota(jnp.int32, (1, width), 1) < n
+    prev_ref[...] = gid_ref[...]
+    gid_ref[...] = jnp.where(valid, rank_ref[...].astype(jnp.int32), 0)
+
+
+def _segment_bounds(mbr_ref, gid_ref, prev_ref, bounds_ref, counts_ref,
+                    bcol_ref, par_out_ref, *, l, n, width, block_n):
+    """Segment min/max, member count and parent of every slot of the
+    CURRENT level, one (B, B) slot-tile × object-tile block at a time
+    (slots on sublanes, objects on lanes; lane reductions only)."""
+    b = block_n
+    n_tiles = width // b
+    sub = jax.lax.broadcasted_iota(jnp.int32, (b, b), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (b, b), 1)
+    eye = sub == lane
+
+    def tile(t):
+        return pl.ds(pl.multiple_of(t * b, b), b)
+
+    def slot_tile(gt, carry):
+        slot = sub + gt * b
+
+        def obj_tile(ot, acc):
+            member = (slot == gid_ref[:, tile(ot)]) & (lane + ot * b < n)
+            m = mbr_ref[:, tile(ot)]
+            lox, loy, hix, hiy, cnt, par = acc
+
+            def red(acc, op, x, fill):  # min/max over the member lanes
+                part = jnp.where(member, x, fill)
+                lanes = jnp.min if op is jnp.minimum else jnp.max
+                return op(acc, lanes(part, axis=1, keepdims=True))
+
+            # parent[slot] = a member's previous-level gid (groups nest,
+            # so every member agrees); max-reduce the (prev + 1) tags.
+            tag = prev_ref[:, tile(ot)].astype(jnp.float32) + 1.0
+            return (
+                red(lox, jnp.minimum, m[0:1], jnp.inf),
+                red(loy, jnp.minimum, m[1:2], jnp.inf),
+                red(hix, jnp.maximum, m[2:3], -jnp.inf),
+                red(hiy, jnp.maximum, m[3:4], -jnp.inf),
+                cnt + jnp.sum(jnp.where(member, 1.0, 0.0), axis=1,
+                              keepdims=True),
+                red(par, jnp.maximum, tag, 0.0),
+            )
+
+        def col(v):
+            return jnp.full((b, 1), v, jnp.float32)
+
+        init = (col(jnp.inf), col(jnp.inf), col(-jnp.inf), col(-jnp.inf),
+                col(0.0), col(0.0))
+        *box, cnt, par = jax.lax.fori_loop(0, n_tiles, obj_tile, init)
+
+        def row(v):  # (B, 1) slot column -> (1, B) lane row
+            return jnp.max(jnp.where(eye, v, -jnp.inf), axis=0, keepdims=True)
+
+        for c, v in enumerate(box + [cnt]):
+            bcol_ref[c, tile(gt), :] = jnp.broadcast_to(v, (b, b))
+        for c, v in enumerate(box):
+            bounds_ref[c:c + 1, tile(gt)] = row(v)
+        counts_ref[:, tile(gt)] = row(cnt)
+        parent = jnp.maximum(row(par), 1.0).astype(jnp.int32) - 1
+        par_out_ref[0, :, tile(gt)] = jnp.where(l > 0, parent, 0)
+        return carry
+
+    jax.lax.fori_loop(0, n_tiles, slot_tile, 0)
 
 
 @functools.partial(
@@ -217,24 +313,29 @@ def build_levels_pallas(
         grid=(levels,),
         in_specs=[pl.BlockSpec((4, width), lambda l: (0, 0))],
         out_specs=[
-            pl.BlockSpec((1, width), lambda l: (l, 0)),
+            pl.BlockSpec((1, 1, width), lambda l: (l, 0, 0)),
             pl.BlockSpec((1, 4, width), lambda l: (l, 0, 0)),
-            pl.BlockSpec((1, width), lambda l: (l, 0)),
+            pl.BlockSpec((1, 1, width), lambda l: (l, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((levels, width), jnp.int32),
+            jax.ShapeDtypeStruct((levels, 1, width), jnp.int32),
             jax.ShapeDtypeStruct((levels, 4, width), jnp.float32),
-            jax.ShapeDtypeStruct((levels, width), jnp.int32),
+            jax.ShapeDtypeStruct((levels, 1, width), jnp.int32),
         ],
         scratch_shapes=[
             pltpu.VMEM((1, width), jnp.int32),
             pltpu.VMEM((1, width), jnp.int32),
+            pltpu.VMEM((1, width), jnp.int32),
+            pltpu.VMEM((1, width), jnp.float32),
             pltpu.VMEM((4, width), jnp.float32),
             pltpu.VMEM((1, width), jnp.float32),
+            pltpu.VMEM((5, width, block_n), jnp.float32),
         ],
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
     )(mbr_cm_in)
-    group_of = group_of[:, :n]
+    group_of = group_of[:, 0, :n]
+    parent = parent[:, 0]
     n_real = group_of.max(axis=1) + 1
     return group_of, mbr_cm[:, :, :n], parent[:, :n], n_real
 

@@ -38,8 +38,9 @@ AND uint16 tiles (tests/test_join.py).  Tile precision only moves the
 pair-visit counts.
 
 VMEM ceiling: the pair masks cost ``2 · Wa · Wb · 4`` bytes of scratch,
-so both level widths together must fit (~2k × 2k at a 32 MB budget);
-past that the mask itself needs block-pair tiling (ROADMAP item 5).
+so both level widths together must fit (~3k × 3k within the 100 MiB
+``VMEM_LIMIT_BYTES``, with the one-hot gathers); past that the mask
+itself needs block-pair tiling (ROADMAP Queue 2 item 3).
 """
 
 from __future__ import annotations
@@ -55,25 +56,29 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.flat import NEVER_MBR, Q_NEVER_MBR, _overlaps
 
+from .pyramid_scan import COMPILER_PARAMS
+
 
 def _pair_overlap_tile(a_tile, b_tile):
-    """(4, BA) × (4, BB) coordinate-major tiles -> (BA, BB) closed-boundary
-    pair overlap.  Tiles are cast to float32 after the VMEM load (uint16
-    grid cells are exact in float32), so one comparison path serves the
-    float32 and compact precisions and HBM only streams the narrow form."""
+    """(4, BA) × (4, BB) coordinate-major tiles -> (BA, BB) int32 0/1
+    closed-boundary pair overlap.  Tiles are cast to float32 after the
+    VMEM load (uint16 grid cells are exact in float32), so one comparison
+    path serves the float32 and compact precisions and HBM only streams
+    the narrow form."""
     a = a_tile.astype(jnp.float32)
     b = b_tile.astype(jnp.float32)
     alx, aly, ahx, ahy = a[0][:, None], a[1][:, None], a[2][:, None], a[3][:, None]
     blx, bly, bhx, bhy = b[0][None, :], b[1][None, :], b[2][None, :], b[3][None, :]
-    return (alx <= bhx) & (blx <= ahx) & (aly <= bhy) & (bly <= ahy)
+    ov = (alx <= bhx) & (blx <= ahx) & (aly <= bhy) & (bly <= ahy)
+    return ov.astype(jnp.int32)
 
 
 def _pair_sweep_kernel(
     a_ref,       # (1, 4, BA) tile of side A, level k
-    pa_ref,      # (1, BA) parent slots of side A, level k
+    pa_ref,      # (1, 1, BA) parent slots of side A, level k
     b_ref,       # (1, 4, BB) tile of side B, level k
-    pb_ref,      # (1, BB) parent slots of side B, level k
-    act_ref,     # out (1, BA, BB) bool
+    pb_ref,      # (1, 1, BB) parent slots of side B, level k
+    act_ref,     # out (1, BA, BB) int8
     prev_ref,    # scratch (Wa, Wb) f32 — level k-1 surviving pairs
     cur_ref,     # scratch (Wa, Wb) f32 — level k surviving pairs
     *,
@@ -87,6 +92,8 @@ def _pair_sweep_kernel(
     k = pl.program_id(0)
     ta = pl.program_id(1)
     tb = pl.program_id(2)
+    rows = pl.ds(pl.multiple_of(ta * block_a, block_a), block_a)
+    cols = pl.ds(pl.multiple_of(tb * block_b, block_b), block_b)
 
     @pl.when((k > 0) & (ta == 0) & (tb == 0))
     def _roll():  # level finished: its pair survivors become the parent mask
@@ -102,15 +109,15 @@ def _pair_sweep_kernel(
     def _tile_body():
         ov = _pair_overlap_tile(a_ref[0], b_ref[0])  # (BA, BB)
 
-        pa_row = pa_ref[0].astype(jnp.int32)
-        pb_row = pb_ref[0].astype(jnp.int32)
+        pa_row = pa_ref[0].astype(jnp.int32)  # (1, BA)
+        pb_row = pb_ref[0].astype(jnp.int32)  # (1, BB)
         if onehot_gather:
             # TPU path: prev[pa, pb] as onehotA^T @ prev @ onehotB — two
             # MXU matmuls instead of a two-axis lane gather.
             ia = jax.lax.broadcasted_iota(jnp.int32, (width_a, block_a), 0)
-            oa = (ia == pa_row[None, :]).astype(jnp.float32)  # (Wa, BA)
+            oa = (ia == pa_row).astype(jnp.float32)  # (Wa, BA)
             ib = jax.lax.broadcasted_iota(jnp.int32, (width_b, block_b), 0)
-            ob = (ib == pb_row[None, :]).astype(jnp.float32)  # (Wb, BB)
+            ob = (ib == pb_row).astype(jnp.float32)  # (Wb, BB)
             pp = jnp.dot(
                 oa.T,
                 jnp.dot(prev_ref[...], ob,
@@ -120,10 +127,11 @@ def _pair_sweep_kernel(
         else:
             # Interpreter path: O(BA·Wb + BA·BB) two-stage take.
             pp = jnp.take(
-                jnp.take(prev_ref[...], pa_row, axis=0), pb_row, axis=1
+                jnp.take(prev_ref[...], pa_row[0], axis=0), pb_row[0], axis=1
             )
-        parent_active = pp > 0.5
+        parent_active = (pp > 0.5).astype(jnp.int32)
 
+        # int32 select: Mosaic cannot select between boolean vectors.
         act = jnp.where(k == 0, ov, parent_active & ov)
         if symmetric:
             # Self-join: the pair mask is symmetric at every level, so
@@ -137,11 +145,9 @@ def _pair_sweep_kernel(
             gb = tb * block_b + jax.lax.broadcasted_iota(
                 jnp.int32, (block_a, block_b), 1
             )
-            act = act & (ga <= gb)
-        cur_ref[
-            pl.ds(ta * block_a, block_a), pl.ds(tb * block_b, block_b)
-        ] = act.astype(jnp.float32)
-        act_ref[0] = act
+            act = act & (ga <= gb).astype(jnp.int32)
+        cur_ref[rows, cols] = act.astype(jnp.float32)
+        act_ref[0] = act.astype(jnp.int8)
 
     if symmetric:
         # Tiles strictly below the diagonal hold no ga <= gb slot pair:
@@ -150,11 +156,8 @@ def _pair_sweep_kernel(
         # the mirrored roll and the epilogue never read garbage.
         @pl.when(tb < ta)
         def _skip_lower():
-            z = jnp.zeros((block_a, block_b), jnp.float32)
-            cur_ref[
-                pl.ds(ta * block_a, block_a), pl.ds(tb * block_b, block_b)
-            ] = z
-            act_ref[0] = z.astype(jnp.bool_)
+            cur_ref[rows, cols] = jnp.zeros((block_a, block_b), jnp.float32)
+            act_ref[0] = jnp.zeros((block_a, block_b), jnp.int8)
 
         @pl.when(tb >= ta)
         def _upper():
@@ -238,20 +241,22 @@ def pair_sweep(
         grid=(k_levels, wa_p // block_a, wb_p // block_b),
         in_specs=[
             pl.BlockSpec((1, 4, block_a), lambda k, ta, tb: (k, 0, ta)),
-            pl.BlockSpec((1, block_a), lambda k, ta, tb: (k, ta)),
+            pl.BlockSpec((1, 1, block_a), lambda k, ta, tb: (k, 0, ta)),
             pl.BlockSpec((1, 4, block_b), lambda k, ta, tb: (k, 0, tb)),
-            pl.BlockSpec((1, block_b), lambda k, ta, tb: (k, tb)),
+            pl.BlockSpec((1, 1, block_b), lambda k, ta, tb: (k, 0, tb)),
         ],
         out_specs=pl.BlockSpec((1, block_a, block_b),
                                lambda k, ta, tb: (k, ta, tb)),
-        out_shape=jax.ShapeDtypeStruct((k_levels, wa_p, wb_p), jnp.bool_),
+        out_shape=jax.ShapeDtypeStruct((k_levels, wa_p, wb_p), jnp.int8),
         scratch_shapes=[
             pltpu.VMEM((wa_p, wb_p), jnp.float32),
             pltpu.VMEM((wa_p, wb_p), jnp.float32),
         ],
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
-    )(a_cm, a_parent, b_cm, b_parent)
-    return act[:, :wa, :wb]
+    )(a_cm, a_parent.reshape(k_levels, 1, wa_p),
+      b_cm, b_parent.reshape(k_levels, 1, wb_p))
+    return act[:, :wa, :wb] != 0
 
 
 def join_epilogue(

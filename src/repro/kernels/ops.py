@@ -1,13 +1,11 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-On this CPU container the kernels execute with ``interpret=True`` (the
-Pallas interpreter runs the kernel body in Python for correctness); on a
-real TPU runtime set ``REPRO_PALLAS_COMPILE=1`` to lower them natively.
+Off the TPU the kernels execute with ``interpret=True`` (the Pallas
+interpreter runs the kernel body for correctness); on a TPU they lower
+natively through Mosaic.
 """
 
 from __future__ import annotations
-
-import os
 
 import jax
 import jax.numpy as jnp
@@ -38,18 +36,16 @@ from .pyramid_scan import pyramid_scan as _pyramid_scan
 from .pyramid_scan import pyramid_scan_compact as _pyramid_scan_compact
 from .pyramid_scan import pyramid_scan_compact8 as _pyramid_scan_compact8
 from .quantize import grid_params as grid_params  # noqa: F401 (re-export)
+from .quantize import quantize_cm_pallas as quantize_cm_pallas  # noqa: F401
 from .quantize import quantize_rows as quantize_rows  # noqa: F401 (re-export)
 from .quantize import quantize_schedule as _quantize_schedule
 from .rmsnorm import rmsnorm as _rmsnorm
 
 
 def interpret_default() -> bool:
-    """Default Pallas execution policy: interpret off TPU, compile on TPU
-    (``REPRO_PALLAS_COMPILE=1`` forces native lowering).  This is the ONE
-    public source of that policy — callers outside ``kernels/`` must not
-    reach for private module state."""
-    if os.environ.get("REPRO_PALLAS_COMPILE", "0") == "1":
-        return False
+    """Default Pallas execution policy: interpret off TPU, compile on TPU.
+    This is the ONE public source of that policy — callers outside
+    ``kernels/`` must not reach for private module state."""
     return jax.default_backend() != "tpu"
 
 
@@ -211,7 +207,7 @@ def fused_join(
 
 def pair_sweep(a_cm, a_parent, b_cm, b_parent, *, block_a: int = 128,
                block_b: int = 128, interpret: bool | None = None,
-               symmetric: bool = False):
+               symmetric: bool = False, onehot_gather: bool | None = None):
     """Raw (K, Wa, Wb) pair-active mask of the synchronized level sweep —
     the join kernel without its epilogue, for tests and benches."""
     if interpret is None:
@@ -219,7 +215,7 @@ def pair_sweep(a_cm, a_parent, b_cm, b_parent, *, block_a: int = 128,
     return _pair_sweep(
         a_cm, a_parent, b_cm, b_parent,
         block_a=block_a, block_b=block_b, interpret=interpret,
-        symmetric=symmetric,
+        symmetric=symmetric, onehot_gather=onehot_gather,
     )
 
 

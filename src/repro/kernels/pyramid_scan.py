@@ -57,36 +57,43 @@ from repro.core.flat import (
 )
 from repro.obs import counters as _obs_counters
 
+# Scoped-VMEM budget of the sweep, pair-sweep and build kernels.  The
+# compiler's default (16 MiB on v5e) is far below the chip's 128 MiB; the
+# VMEM-resident kernels keep whole (Q, W) survivor masks on chip, so their
+# widest compilable schedule is set by this limit.
+VMEM_LIMIT_BYTES = 100 * 2**20
+COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES)
 
-def _overlap_tile(q_ref, mbr_tile):
-    """(Q, 4) resident queries vs (4, BW) coordinate-major tile -> (Q, BW).
+# Tiles per SMEM block of the streamed sweep's window-offset table.
+STREAM_META_BLOCK = 1024
 
-    Works for float32 tiles and for uint16 compact tiles (tiles are cast
-    to the query dtype — int32 for quantized sweeps — after the VMEM
+
+# Survivor masks travel as int32 0/1 inside every sweep kernel: Mosaic
+# cannot select between boolean vectors, and the level recurrence below is
+# a select on the level index.  Masks leave the kernels as int8.
+def _overlap_tile(q, mbr_tile):
+    """(Q, 4) resident queries vs (4, BW) coordinate-major tile -> (Q, BW)
+    int32 0/1.
+
+    Works for float32 tiles and for uint16/uint8 compact tiles (tiles are
+    cast to the query dtype — int32 for quantized sweeps — after the VMEM
     load, so HBM only ever streams the narrow form)."""
-    if mbr_tile.dtype != q_ref.dtype:
-        mbr_tile = mbr_tile.astype(q_ref.dtype)
-    lx, ly, hx, hy = mbr_tile[0, :], mbr_tile[1, :], mbr_tile[2, :], mbr_tile[3, :]
-    qlx = q_ref[:, 0][:, None]
-    qly = q_ref[:, 1][:, None]
-    qhx = q_ref[:, 2][:, None]
-    qhy = q_ref[:, 3][:, None]
-    return (
-        (lx[None, :] <= qhx)
-        & (qlx <= hx[None, :])
-        & (ly[None, :] <= qhy)
-        & (qly <= hy[None, :])
-    )
+    if mbr_tile.dtype != q.dtype:
+        mbr_tile = mbr_tile.astype(q.dtype)
+    lx, ly, hx, hy = (mbr_tile[c:c + 1, :] for c in range(4))  # (1, BW)
+    qlx, qly, qhx, qhy = (q[:, c:c + 1] for c in range(4))      # (Q, 1)
+    ov = (lx <= qhx) & (qlx <= hx) & (ly <= qhy) & (qly <= hy)
+    return ov.astype(jnp.int32)
 
 
 def _act_formula(ov, parent_active, *, l, t, block_w, root_unconditional,
                  uncond_from):
-    """The shared per-tile active-mask recurrence of every sweep kernel."""
+    """The shared per-tile active-mask recurrence of every sweep kernel
+    (int32 0/1 operands and result)."""
     if root_unconditional:
         # The pointer search always examines the root node (slot 0).
-        col = jax.lax.broadcasted_iota(jnp.int32, (1, block_w), 1)[0]
-        root = (t * block_w + col) == 0
-        act0 = jnp.broadcast_to(root[None, :], ov.shape)
+        col = jax.lax.broadcasted_iota(jnp.int32, ov.shape, 1)
+        act0 = (t * block_w + col == 0).astype(jnp.int32)
     else:
         act0 = ov
     # Levels at or past ``uncond_from`` are FLAT appendices (the live-update
@@ -98,16 +105,32 @@ def _act_formula(ov, parent_active, *, l, t, block_w, root_unconditional,
     )
 
 
+def _gather_parents(mask, parent_row, *, onehot_gather):
+    """``mask[:, parent_row]`` -> (Q, BW) int32 0/1 for an f32 0/1 mask
+    (Q, M) and a (1, BW) int32 row of column indices into it."""
+    if onehot_gather:
+        # TPU path: the gather as a one-hot matmul on the MXU,
+        # onehot[p, j] = (p == parent[j]) — no lane gather needed.
+        iota = jax.lax.broadcasted_iota(
+            jnp.int32, (mask.shape[1], parent_row.shape[1]), 0
+        )
+        onehot = (iota == parent_row).astype(jnp.float32)
+        pa = jnp.dot(mask, onehot, preferred_element_type=jnp.float32)
+    else:
+        # Interpreter path: O(Q·BW) column gather instead of O(Q·M·BW).
+        pa = jnp.take(mask, parent_row[0], axis=1)
+    return (pa > 0.5).astype(jnp.int32)
+
+
 def _sweep_kernel(
     q_ref,       # (Q, 4) f32, resident
     mbr_ref,     # (1, 4, BW) f32 tile of level l
-    parent_ref,  # (1, BW) i32 tile of level l
-    act_ref,     # out (1, Q, BW) bool
+    parent_ref,  # (1, 1, BW) i32 tile of level l
+    act_ref,     # out (1, Q, BW) int8
     prev_ref,    # scratch (Q, W) f32 — level l-1 survivors
     cur_ref,     # scratch (Q, W) f32 — level l survivors
     *,
     block_w: int,
-    width: int,
     root_unconditional: bool,
     onehot_gather: bool,
     uncond_from: int,
@@ -119,27 +142,18 @@ def _sweep_kernel(
     def _roll():  # level finished: its survivors become the parent mask
         prev_ref[...] = cur_ref[...]
 
-    ov = _overlap_tile(q_ref, mbr_ref[0])  # (Q, BW)
-
-    parent_row = parent_ref[0].astype(jnp.int32)  # uint16 on the compact path
-    if onehot_gather:
-        # TPU path: parent gather as a one-hot matmul on the MXU,
-        # onehot[p, j] = (p == parent[j]) — no lane gather needed.
-        iota = jax.lax.broadcasted_iota(jnp.int32, (width, block_w), 0)
-        onehot = (iota == parent_row[None, :]).astype(jnp.float32)
-        pa = jnp.dot(prev_ref[...], onehot, preferred_element_type=jnp.float32)
-    else:
-        # Interpreter path: O(Q·BW) column gather instead of O(Q·W·BW).
-        pa = jnp.take(prev_ref[...], parent_row, axis=1)
-    parent_active = pa > 0.5
-
+    ov = _overlap_tile(q_ref[...], mbr_ref[0])  # (Q, BW)
+    parent_active = _gather_parents(
+        prev_ref[...], parent_ref[0].astype(jnp.int32),  # uint16 if compact
+        onehot_gather=onehot_gather,
+    )
     act = _act_formula(
         ov, parent_active, l=l, t=t, block_w=block_w,
         root_unconditional=root_unconditional, uncond_from=uncond_from,
     )
-
-    cur_ref[:, pl.ds(t * block_w, block_w)] = act.astype(jnp.float32)
-    act_ref[0] = act
+    col = pl.multiple_of(t * block_w, block_w)
+    cur_ref[:, pl.ds(col, block_w)] = act.astype(jnp.float32)
+    act_ref[0] = act.astype(jnp.int8)
 
 
 def _hier_sweep_kernel(
@@ -147,13 +161,12 @@ def _hier_sweep_kernel(
     q16_ref,     # (Q, 4) i32 — queries on the fine uint16 grid
     mbr8_ref,    # (1, 4, BW) u8 tile (level index clamped to < split)
     mbr16_ref,   # (1, 4, BW) u16 tile (level index clamped to >= split)
-    parent_ref,  # (1, BW)
-    act_ref,     # out (1, Q, BW) bool
+    parent_ref,  # (1, 1, BW)
+    act_ref,     # out (1, Q, BW) int8
     prev_ref,    # scratch (Q, W) f32
     cur_ref,     # scratch (Q, W) f32
     *,
     block_w: int,
-    width: int,
     split: int,
     root_unconditional: bool,
     onehot_gather: bool,
@@ -170,39 +183,35 @@ def _hier_sweep_kernel(
     def _roll():
         prev_ref[...] = cur_ref[...]
 
-    ov8 = _overlap_tile(q8_ref, mbr8_ref[0])
-    ov16 = _overlap_tile(q16_ref, mbr16_ref[0])
+    ov8 = _overlap_tile(q8_ref[...], mbr8_ref[0])
+    ov16 = _overlap_tile(q16_ref[...], mbr16_ref[0])
     ov = jnp.where(l < split, ov8, ov16)
-
-    parent_row = parent_ref[0].astype(jnp.int32)
-    if onehot_gather:
-        iota = jax.lax.broadcasted_iota(jnp.int32, (width, block_w), 0)
-        onehot = (iota == parent_row[None, :]).astype(jnp.float32)
-        pa = jnp.dot(prev_ref[...], onehot, preferred_element_type=jnp.float32)
-    else:
-        pa = jnp.take(prev_ref[...], parent_row, axis=1)
-    parent_active = pa > 0.5
-
+    parent_active = _gather_parents(
+        prev_ref[...], parent_ref[0].astype(jnp.int32),
+        onehot_gather=onehot_gather,
+    )
     act = _act_formula(
         ov, parent_active, l=l, t=t, block_w=block_w,
         root_unconditional=root_unconditional, uncond_from=uncond_from,
     )
-
-    cur_ref[:, pl.ds(t * block_w, block_w)] = act.astype(jnp.float32)
-    act_ref[0] = act
+    col = pl.multiple_of(t * block_w, block_w)
+    cur_ref[:, pl.ds(col, block_w)] = act.astype(jnp.float32)
+    act_ref[0] = act.astype(jnp.int8)
 
 
 def _stream_sweep_kernel(
-    winoff_ref,  # (L, T) SMEM i32 — parent-window start of every tile
+    meta_ref,    # SMEM (1, 2, MB) i32 — window starts of this/next step
     q_ref,       # (Q, 4) VMEM, resident
     mbr_hbm,     # (L, 4, Wp) ANY (HBM) — streamed, never VMEM-resident
-    par_hbm,     # (L, Wp) ANY (HBM)
-    act_ref,     # out (1, Q, BW) bool
+    par_hbm,     # (L, 1, Wp) ANY (HBM)
+    act_ref,     # out (1, Q, BW) int8
+    mask_hbm,    # out ANY (2, Q, Wp) f32 — ping-pong survivor masks (by
+                 # level); an output only because Mosaic allocates scratch
+                 # in VMEM/SMEM alone — the caller drops it
     mbr_buf,     # VMEM (2, 4, BW) — double-buffered tile landing slots
-    par_buf,     # VMEM (2, BW)
+    par_buf,     # VMEM (2, 1, BW)
     win_buf,     # VMEM (2, Q, win_w) f32 — double-buffered parent windows
-    cur_buf,     # VMEM (1, Q, BW) f32 — this tile's survivors, staged out
-    mask_hbm,    # ANY (2, Q, Wp) f32 — ping-pong survivor masks (by level)
+    cur_buf,     # VMEM (Q, BW) f32 — this tile's survivors, staged out
     sem_in,      # DMA sems (2 slots × {mbr, parent})
     sem_win,     # DMA sem — level-boundary window read
     sem_pre,     # DMA sem — next-step window prefetch
@@ -211,7 +220,7 @@ def _stream_sweep_kernel(
     block_w: int,
     win_w: int,
     n_tiles: int,
-    n_steps: int,
+    meta_block: int,
     root_unconditional: bool,
     onehot_gather: bool,
     uncond_from: int,
@@ -224,9 +233,11 @@ def _stream_sweep_kernel(
     generate, written out so the survivor masks can ride an HBM scratch.
     Level ``l`` writes its survivors to ``mask_hbm[l % 2]`` and reads its
     parents from ``mask_hbm[(l+1) % 2]`` (= parity of ``l-1``), but only
-    the ``win_w``-wide window starting at ``winoff[l, t]`` that this
-    tile's parent slots actually span, so VMEM never holds a full-width
-    mask.
+    the ``win_w``-wide window starting at this step's ``meta`` offset that
+    the tile's parent slots actually span, so VMEM never holds a
+    full-width mask.  The offsets arrive in SMEM blocks of ``meta_block``
+    tiles — row 0 for this step, row 1 for the next — so SMEM never holds
+    the whole (levels × tiles) table.
 
     Dead-window skip: the window for step ``s+1`` is fetched (into the
     other ``win_buf`` slot) before step ``s+1``'s tile copies are issued.
@@ -240,29 +251,33 @@ def _stream_sweep_kernel(
     t = pl.program_id(1)
     step = l * n_tiles + t
     slot = jax.lax.rem(step, 2)
+    m = jax.lax.rem(t, meta_block)
+    off = meta_ref[0, 0, m]    # < 0: statically-empty tile
+    off1 = meta_ref[0, 1, m]   # the next step's; < 0 also past the end
 
     def tile_copies(li, ti, s):
+        col = pl.multiple_of(ti * block_w, block_w)
         return (
             pltpu.make_async_copy(
-                mbr_hbm.at[pl.ds(li, 1), :, pl.ds(ti * block_w, block_w)],
-                mbr_buf.at[pl.ds(s, 1)],
+                mbr_hbm.at[li, :, pl.ds(col, block_w)],
+                mbr_buf.at[s],
                 sem_in.at[s, 0],
             ),
             pltpu.make_async_copy(
-                par_hbm.at[pl.ds(li, 1), pl.ds(ti * block_w, block_w)],
-                par_buf.at[pl.ds(s, 1)],
+                par_hbm.at[li, :, pl.ds(col, block_w)],
+                par_buf.at[s],
                 sem_in.at[s, 1],
             ),
         )
 
-    def win_copy(li, ti, s, sem):
-        # off < 0 marks a statically-empty tile; the copy is never
-        # started for one, the clamp only keeps the descriptor in range.
-        off = jnp.maximum(winoff_ref[li, ti], 0)
+    def win_copy(li, o, s, sem):
+        # Offsets are multiples of 128 lanes (``parent_windows``); a
+        # negative one marks a statically-empty tile, whose copy is never
+        # started — the clamp only keeps the descriptor in range.
+        o = pl.multiple_of(jnp.maximum(o, 0), 128)
         return pltpu.make_async_copy(
-            mask_hbm.at[pl.ds(jax.lax.rem(li + 1, 2), 1), :,
-                        pl.ds(off, win_w)],
-            win_buf.at[pl.ds(s, 1)],
+            mask_hbm.at[jax.lax.rem(li + 1, 2), :, pl.ds(o, win_w)],
+            win_buf.at[s],
             sem,
         )
 
@@ -273,7 +288,7 @@ def _stream_sweep_kernel(
 
     gated = gated_at(l)
     boundary = t == 0
-    empty = winoff_ref[l, t] < 0
+    empty = off < 0
 
     @pl.when(step == 0)
     def _warmup():  # first tile has no previous step to prefetch it
@@ -283,14 +298,11 @@ def _stream_sweep_kernel(
     # Level-boundary window: read synchronously at this step (the mask of
     # level l-1 is complete once level l starts, but was not yet at the
     # previous step, when the boundary tile's copies were issued).
-    bwin = win_copy(l, t, slot, sem_win)
+    bwin = win_copy(l, off, slot, sem_win)
 
     @pl.when(gated & boundary & ~empty)
-    def _boundary_win_start():
+    def _boundary_win():
         bwin.start()
-
-    @pl.when(gated & boundary & ~empty)
-    def _boundary_win_wait():
         bwin.wait()
 
     # Prefetch for step s+1 with dead-window skip: fetch the next tile's
@@ -301,18 +313,18 @@ def _stream_sweep_kernel(
     l1 = jax.lax.div(nxt, n_tiles)
     t1 = jax.lax.rem(nxt, n_tiles)
     s1 = jax.lax.rem(nxt, 2)
-    empty1 = (nxt < n_steps) & (winoff_ref[jnp.minimum(l1, n_steps // n_tiles - 1), t1] < 0)
+    empty1 = off1 < 0
     skippable1 = gated_at(l1) & (t1 != 0)
-    pwin = win_copy(jnp.minimum(l1, n_steps // n_tiles - 1), t1, s1, sem_pre)
+    pwin = win_copy(l1, off1, s1, sem_pre)
 
-    @pl.when((nxt < n_steps) & skippable1 & ~empty1)
+    @pl.when(skippable1 & ~empty1)
     def _prefetch_win():
         pwin.start()
         pwin.wait()
 
-    live1 = jnp.max(win_buf[pl.ds(s1, 1)]) > 0.5
+    live1 = jnp.max(win_buf[s1]) > 0.5
 
-    @pl.when((nxt < n_steps) & ~empty1 & (live1 | ~skippable1))
+    @pl.when(~empty1 & (live1 | ~skippable1))
     def _prefetch():  # overlap: next tile's copy rides this tile's compute
         for c in tile_copies(l1, t1, s1):
             c.start()
@@ -320,7 +332,7 @@ def _stream_sweep_kernel(
     # Wait for our own tile — unless the previous step skipped its DMA.
     # ``live`` re-reads the same window slot the skip decision used (it
     # is untouched in between), so the predicate matches exactly.
-    live = jnp.max(win_buf[pl.ds(slot, 1)]) > 0.5
+    live = jnp.max(win_buf[slot]) > 0.5
     fetched = ~empty & (live | ~gated | boundary)
 
     @pl.when(fetched)
@@ -328,24 +340,18 @@ def _stream_sweep_kernel(
         for c in tile_copies(l, t, slot):
             c.wait()
 
-    ov = _overlap_tile(q_ref, mbr_buf[pl.ds(slot, 1)][0])  # (Q, BW)
-
-    parent_row = par_buf[pl.ds(slot, 1)][0].astype(jnp.int32)
+    ov = _overlap_tile(q_ref[...], mbr_buf[slot])  # (Q, BW)
     # Window-local parent slot.  Real slots are guaranteed in-window by
     # ``parent_windows``; padded slots may clamp to a garbage column, but
-    # their sentinel MBRs make ``ov`` False so the AND discards it.  At
+    # their sentinel MBRs make ``ov`` 0 so the AND discards it.  At
     # gated=False steps win_buf is stale/uninitialized — same argument:
     # the selected branch of ``_act_formula`` never reads parent_active.
-    loc = jnp.clip(parent_row - winoff_ref[l, t], 0, win_w - 1)
-    win = win_buf[pl.ds(slot, 1)][0]  # (Q, win_w)
-    if onehot_gather:
-        iota = jax.lax.broadcasted_iota(jnp.int32, (win_w, block_w), 0)
-        onehot = (iota == loc[None, :]).astype(jnp.float32)
-        pa = jnp.dot(win, onehot, preferred_element_type=jnp.float32)
-    else:
-        pa = jnp.take(win, loc, axis=1)
-    parent_active = pa > 0.5
-
+    loc = jnp.clip(
+        par_buf[slot].astype(jnp.int32) - jnp.maximum(off, 0), 0, win_w - 1
+    )
+    parent_active = _gather_parents(
+        win_buf[slot], loc, onehot_gather=onehot_gather
+    )
     act = _act_formula(
         ov, parent_active, l=l, t=t, block_w=block_w,
         root_unconditional=root_unconditional, uncond_from=uncond_from,
@@ -354,18 +360,18 @@ def _stream_sweep_kernel(
     # is stale garbage there — but its true activations are provably all
     # zero (sentinel MBRs; the root mask is slot 0 of tile 0), so force
     # exactly that.
-    act = act & ~empty
+    act = jnp.where(empty, 0, act)
 
-    cur_buf[0] = act.astype(jnp.float32)
+    cur_buf[...] = act.astype(jnp.float32)
     out_copy = pltpu.make_async_copy(
         cur_buf,
-        mask_hbm.at[pl.ds(jax.lax.rem(l, 2), 1), :,
-                    pl.ds(t * block_w, block_w)],
+        mask_hbm.at[jax.lax.rem(l, 2), :,
+                    pl.ds(pl.multiple_of(t * block_w, block_w), block_w)],
         sem_out,
     )
     out_copy.start()
     out_copy.wait()
-    act_ref[0] = act
+    act_ref[0] = act.astype(jnp.int8)
 
 
 def parent_windows(
@@ -411,12 +417,15 @@ def parent_windows(
         hi = np.concatenate([np.where(valid, p, -1), np.full(pad, -1)])
         tmin[l] = lo.reshape(n_tiles, block_w).min(axis=1)
         tmax[l] = hi.reshape(n_tiles, block_w).max(axis=1)
-    spans = np.where(tmax >= tmin, tmax - tmin + 1, 1)
+    # Window starts are aligned down to ``win_unit`` lanes: the chip's DMA
+    # engine only slices the lane dimension at whole (8, 128) tiles.
+    base = np.where(tmin == big, 0, tmin // win_unit * win_unit)
+    spans = np.where(tmax >= tmin, tmax - base + 1, 1)
     span = max(1, int(spans.max()))
     win_w = min(wp, int(-(-span // win_unit)) * win_unit)
     win_w = max(win_w, min(wp, win_unit))
-    off = np.where(tmin == big, 0, np.minimum(tmin, wp - win_w))
-    off = np.clip(off, 0, max(wp - win_w, 0)).astype(np.int32)
+    off = np.clip(np.minimum(base, wp - win_w), 0, max(wp - win_w, 0))
+    off = off.astype(np.int32)
     # Statically-empty tiles (every slot past n_real[l]) can never
     # activate — sentinel MBRs overlap nothing and the root mask is slot
     # 0 only — so mark them with off = -1: the streaming kernel skips
@@ -486,11 +495,11 @@ def level_sweep(
         # gather is cheaper (O(Q·W) vs O(Q·W²/BW)) where gathers are free.
         onehot_gather = not interpret
     uncond = levels if uncond_from is None else uncond_from
+    parent = parent.reshape(levels, 1, wp)  # a (1, BW) parent row per tile
     if not stream:
         kernel = functools.partial(
             _sweep_kernel,
             block_w=block_w,
-            width=wp,
             root_unconditional=root_unconditional,
             onehot_gather=onehot_gather,
             uncond_from=uncond,
@@ -501,58 +510,83 @@ def level_sweep(
             in_specs=[
                 pl.BlockSpec((q, 4), lambda l, t: (0, 0)),
                 pl.BlockSpec((1, 4, block_w), lambda l, t: (l, 0, t)),
-                pl.BlockSpec((1, block_w), lambda l, t: (l, t)),
+                pl.BlockSpec((1, 1, block_w), lambda l, t: (l, 0, t)),
             ],
             out_specs=pl.BlockSpec((1, q, block_w), lambda l, t: (l, 0, t)),
-            out_shape=jax.ShapeDtypeStruct((levels, q, wp), jnp.bool_),
+            out_shape=jax.ShapeDtypeStruct((levels, q, wp), jnp.int8),
             scratch_shapes=[
                 pltpu.VMEM((q, wp), jnp.float32),
                 pltpu.VMEM((q, wp), jnp.float32),
             ],
+            compiler_params=COMPILER_PARAMS,
             interpret=interpret,
         )(queries, mbr_cm, parent)
-        return act[:, :, :w]
+        return act[:, :, :w] != 0
     if win_off is None or win_w is None:
         raise ValueError(
             "stream=True needs (win_off, win_w) from parent_windows()"
         )
     win_w = min(win_w, wp)
+    meta, meta_block = _stream_meta(jnp.asarray(win_off, jnp.int32))
     kernel = functools.partial(
         _stream_sweep_kernel,
         block_w=block_w,
         win_w=win_w,
         n_tiles=n_tiles,
-        n_steps=levels * n_tiles,
+        meta_block=meta_block,
         root_unconditional=root_unconditional,
         onehot_gather=onehot_gather,
         uncond_from=uncond,
     )
-    act = pl.pallas_call(
+    act, _ = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((levels, n_tiles), lambda l, t: (0, 0),
+            pl.BlockSpec((1, 2, meta_block),
+                         lambda l, t: (l, 0, t // meta_block),
                          memory_space=pltpu.SMEM),
             pl.BlockSpec((q, 4), lambda l, t: (0, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, q, block_w), lambda l, t: (l, 0, t)),
-        out_shape=jax.ShapeDtypeStruct((levels, q, wp), jnp.bool_),
+        out_specs=[
+            pl.BlockSpec((1, q, block_w), lambda l, t: (l, 0, t)),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((levels, q, wp), jnp.int8),
+            jax.ShapeDtypeStruct((2, q, wp), jnp.float32),
+        ],
         scratch_shapes=[
             pltpu.VMEM((2, 4, block_w), mbr_cm.dtype),
-            pltpu.VMEM((2, block_w), parent.dtype),
+            pltpu.VMEM((2, 1, block_w), parent.dtype),
             pltpu.VMEM((2, q, win_w), jnp.float32),
-            pltpu.VMEM((1, q, block_w), jnp.float32),
-            pltpu.ANY((2, q, wp), jnp.float32),
+            pltpu.VMEM((q, block_w), jnp.float32),
             pltpu.SemaphoreType.DMA((2, 2)),
             pltpu.SemaphoreType.DMA,
             pltpu.SemaphoreType.DMA,
             pltpu.SemaphoreType.DMA,
         ],
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
-    )(jnp.asarray(win_off, jnp.int32), queries, mbr_cm, parent)
-    return act[:, :, :w]
+    )(meta, queries, mbr_cm, parent)
+    return act[:, :, :w] != 0
+
+
+def _stream_meta(win_off):
+    """(L, T) window starts -> ((L, 2, Tp) table, block) for the streamed
+    sweep's SMEM blocks: row 0 holds each step's own window start, row 1
+    the next step's (-1 past the last step), padded with -1 to a whole
+    number of ``block``-tile SMEM blocks."""
+    levels, n_tiles = win_off.shape
+    flat_off = win_off.reshape(-1)
+    nxt = jnp.concatenate([flat_off[1:], jnp.full((1,), -1, jnp.int32)])
+    meta = jnp.stack([win_off, nxt.reshape(levels, n_tiles)], axis=1)
+    block = min(n_tiles, STREAM_META_BLOCK)
+    pad = (-n_tiles) % block
+    if pad:
+        meta = jnp.pad(meta, ((0, 0), (0, 0), (0, pad)), constant_values=-1)
+    return meta, block
 
 
 @functools.partial(
@@ -608,7 +642,6 @@ def level_sweep_hier(
     kernel = functools.partial(
         _hier_sweep_kernel,
         block_w=block_w,
-        width=wp,
         split=split,
         root_unconditional=root_unconditional,
         onehot_gather=onehot_gather,
@@ -631,17 +664,18 @@ def level_sweep_hier(
                 (1, 4, block_w),
                 lambda l, t: (jnp.maximum(l - split, 0), 0, t),
             ),
-            pl.BlockSpec((1, block_w), lambda l, t: (l, t)),
+            pl.BlockSpec((1, 1, block_w), lambda l, t: (l, 0, t)),
         ],
         out_specs=pl.BlockSpec((1, q, block_w), lambda l, t: (l, 0, t)),
-        out_shape=jax.ShapeDtypeStruct((levels, q, wp), jnp.bool_),
+        out_shape=jax.ShapeDtypeStruct((levels, q, wp), jnp.int8),
         scratch_shapes=[
             pltpu.VMEM((q, wp), jnp.float32),
             pltpu.VMEM((q, wp), jnp.float32),
         ],
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
-    )(q8, q16, mbr8, mbr16, parent)
-    return act[:, :, :w]
+    )(q8, q16, mbr8, mbr16, parent.reshape(levels, 1, wp))
+    return act[:, :, :w] != 0
 
 
 def _quantize_queries(queries, origin, inv_cell, cells: int):

@@ -110,7 +110,8 @@ def _quantize_kernel(mbr_ref, org_ref, inv_ref, out_ref, *, block_w: int):
     cell = jnp.where(is_lo, jnp.floor(t), jnp.ceil(t))
     cell = jnp.clip(cell, 0.0, float(CELLS))
     cell = jnp.where(is_lo & (v == jnp.inf), float(CELLS + 1), cell)
-    out_ref[0] = cell.astype(jnp.uint16)
+    # Mosaic has no float -> uint16 cast; the cells fit int32 exactly.
+    out_ref[0] = cell.astype(jnp.int32).astype(jnp.uint16)
 
 
 @functools.partial(jax.jit, static_argnames=("block_w", "interpret"))

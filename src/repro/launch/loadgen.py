@@ -20,6 +20,7 @@ import sys
 
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs import counters as obs_counters
 from repro.obs import trace as obs_trace
 from repro.serve import ServerConfig, ServingFrontEnd
@@ -66,6 +67,7 @@ def build_sweep(args, last_front=None):
 
 
 def main(argv=None) -> int:
+    enable_compile_cache()
     p = argparse.ArgumentParser(description=__doc__)
     p.add_argument("--qps", default="25,100,400" if TINY else "50,200,800")
     p.add_argument("--duration", type=float, default=0.4 if TINY else 2.0)

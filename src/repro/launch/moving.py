@@ -211,6 +211,9 @@ def main(argv=None):  # pragma: no cover - CLI demo
     import argparse
     import time
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--ticks", type=int, default=100)
     ap.add_argument("--objects", type=int, default=128)

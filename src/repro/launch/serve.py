@@ -21,6 +21,7 @@ import numpy as np
 
 from repro.configs import registry
 from repro.launch import steps as step_lib
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as T
 
 
@@ -79,6 +80,7 @@ def serve(
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama32_1b")
     ap.add_argument("--full", action="store_true")

@@ -523,6 +523,9 @@ class SpatialServer:
 
 def main():
     from repro.core import datasets, flat, mqrtree
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     ap = argparse.ArgumentParser()
     ap.add_argument("--n", type=int, default=2000)

@@ -23,6 +23,7 @@ from repro.configs import registry
 from repro.data import DataConfig, SyntheticLM
 from repro.ft import FailureInjector, StragglerMonitor
 from repro.launch import steps as step_lib
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as T
 from repro.optim import AdamWConfig, adamw
 from repro.optim.compress import ef_int8_state
@@ -107,6 +108,7 @@ def train(
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama32_1b")
     ap.add_argument("--full", action="store_true", help="full (non-smoke) config")

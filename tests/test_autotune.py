@@ -66,9 +66,10 @@ def test_candidates_per_level_plan_only_for_plain_float32():
 
 
 def test_candidates_block_ws_bounded_by_width():
-    # a 200-wide grid never proposes 512-wide tiles (pure padding)
-    assert {c.block_w for c in candidates(200, 8)} <= {64, 128, 256}
-    assert {c.block_w for c in candidates(4096, 8)} >= {64, 128, 256, 512}
+    # a 200-wide grid never proposes 512-wide tiles (pure padding), and
+    # no grid proposes a tile narrower than the chip's 128 lanes
+    assert {c.block_w for c in candidates(200, 8)} == {128, 256}
+    assert {c.block_w for c in candidates(4096, 8)} == {128, 256, 512}
 
 
 def test_candidates_query_block_only_for_large_batches():
@@ -111,19 +112,21 @@ def test_tune_picks_fastest_and_skips_raising():
         delay = 0.02 if cfg is slow else 0.0
         return lambda: time.sleep(delay)
 
-    best, timings = tune(make_run, [slow, broken, fast], iters=2)
+    best, timings, refused = tune(make_run, [slow, broken, fast], iters=2)
     assert best == fast
     assert broken not in timings
     assert timings[slow] > timings[fast]
+    assert refused == {broken: "RuntimeError: unsupported tile"}
 
 
 def test_tune_all_raising_falls_back_to_default():
     def make_run(cfg):
         raise RuntimeError("no runtime")
 
-    best, timings = tune(make_run, [TileConfig(64), TileConfig(256)])
+    best, timings, refused = tune(make_run, [TileConfig(64), TileConfig(256)])
     assert best == TileConfig()
     assert timings == {}
+    assert set(refused) == {TileConfig(64), TileConfig(256)}
 
 
 # ---------------------------------------------------------------------------
@@ -173,6 +176,7 @@ def test_autotune_on_tunes_and_caches_in_artifacts():
         qs.shape[0], "float32", False,
     )
     assert isinstance(cfg, TileConfig)
+    assert idx.artifacts.tune_refusals == {}
     # same shape again: the cached winner is reused, not re-timed
     idx.region(qs)
     assert len(idx.artifacts.tuned) == 1

@@ -65,10 +65,11 @@ def test_device_schedule_scan_parity(name):
     assert np.array_equal(np.asarray(h_visits), np.asarray(d_visits))
 
 
-def test_build_kernel_onehot_matches_gather():
-    """The MXU one-hot segment/densify path (TPU lowering) and the
+@pytest.mark.parametrize("n", [1, 130, 300])
+def test_build_kernel_onehot_matches_gather(n):
+    """The gather-free tiled subdivision (TPU lowering) and the
     interpreter's gather path must emit the same build."""
-    data = datasets.uniform_squares(300, seed=5).astype(np.float32)
+    data = datasets.uniform_squares(n, seed=5).astype(np.float32)
     a = build_levels_pallas(jnp.asarray(data), levels=6, interpret=True,
                             onehot_gather=True)
     b = build_levels_pallas(jnp.asarray(data), levels=6, interpret=True,

@@ -93,6 +93,30 @@ def test_join_parity_matrix(structure, backend):
     )
 
 
+@pytest.mark.parametrize("symmetric", [False, True])
+def test_pair_sweep_onehot_matches_column_gather(symmetric):
+    """The MXU one-hot double parent gather (the TPU path) and the
+    interpreter's two-stage take must produce the same pair sweep."""
+    import jax.numpy as jnp
+
+    from repro.kernels.ops import pair_sweep
+
+    a = SpatialIndex.build(_data("onehot-a", "uniform_squares", 300), structure="mqr")
+    b = a if symmetric else SpatialIndex.build(
+        _data("onehot-b", "uniform_squares", 200), structure="mqr")
+    k = min(a.schedule.levels, b.schedule.levels)
+    args = [jnp.asarray(x) for x in (
+        a.schedule.mbr_cm[:k], a.schedule.parent[:k],
+        b.schedule.mbr_cm[:k], b.schedule.parent[:k],
+    )]
+    one = pair_sweep(*args, interpret=True, onehot_gather=True,
+                     symmetric=symmetric)
+    col = pair_sweep(*args, interpret=True, onehot_gather=False,
+                     symmetric=symmetric)
+    assert np.asarray(col).any()
+    assert np.array_equal(np.asarray(one), np.asarray(col))
+
+
 @pytest.mark.parametrize("structure", STRUCTURES)
 def test_join_compact_parity_and_conservative_visits(structure):
     """precision="compact" joins on the joint uint16 grid: identical

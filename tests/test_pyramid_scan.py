@@ -108,15 +108,56 @@ def test_pyramid_schedule_matches_bulk_search():
         assert np.array_equal(hits[i], ref)
 
 
-def test_onehot_gather_matches_column_gather():
-    """The MXU one-hot parent gather (TPU path) and the interpreter's
-    column gather must produce the same sweep."""
+@pytest.mark.parametrize(
+    "variant", ["float32", "compact", "stream", "stream_compact", "hier"]
+)
+def test_onehot_gather_matches_column_gather(variant):
+    """The MXU one-hot parent gather (the TPU path) and the interpreter's
+    column gather must produce the same sweep, in every sweep kernel."""
     data = datasets.uniform_squares(200, seed=11)
     sched = flat.level_schedule(flat.flatten(mqrtree.build(data)))
     qs = jnp.asarray(datasets.region_queries(data, 4, seed=12), jnp.float32)
-    mb, pa = jnp.asarray(sched.mbr_cm), jnp.asarray(sched.parent)
-    a = level_sweep(qs, mb, pa, interpret=True, onehot_gather=True)
-    b = level_sweep(qs, mb, pa, interpret=True, onehot_gather=False)
+    qsched = ops.quantize_schedule(sched, interpret=True, upper8=True)
+
+    def grid(inv_cell, cells):  # outward query rounding onto a tile grid
+        t = (qs - qsched.origin[None, :]) * inv_cell[None, :]
+        q = jnp.concatenate([jnp.floor(t[:, :2]), jnp.ceil(t[:, 2:])], 1)
+        return jnp.clip(q, 0.0, float(cells)).astype(jnp.int32)
+
+    qq16 = grid(qsched.inv_cell, qsched.cells)
+    if variant == "hier":
+        qq8 = grid(qsched.inv_cell8, qsched.cells8)
+        split = qsched.split
+
+        def sweep(onehot):
+            return ops.level_sweep_hier(
+                qq8, qq16, jnp.asarray(qsched.mbr_q8),
+                jnp.asarray(qsched.mbr_q[split:]),
+                jnp.asarray(qsched.parent_q), split=split, interpret=True,
+                onehot_gather=onehot,
+            )
+    else:
+        compact = variant.endswith("compact")
+        q = qq16 if compact else qs
+        mb = qsched.mbr_q if compact else sched.mbr_cm
+        pa = qsched.parent_q if compact else sched.parent
+        stream = variant.startswith("stream")
+        win_off, win_w = (
+            ops.parent_windows(pa, sched.n_real, block_w=128)
+            if stream else (None, None)
+        )
+
+        def sweep(onehot):
+            return level_sweep(
+                q, jnp.asarray(mb), jnp.asarray(pa), interpret=True,
+                onehot_gather=onehot, stream=stream,
+                win_off=None if win_off is None else jnp.asarray(win_off),
+                win_w=win_w,
+            )
+
+    a = sweep(True)
+    b = sweep(False)
+    assert np.asarray(b).any()
     assert np.array_equal(np.asarray(a), np.asarray(b))
 
 
