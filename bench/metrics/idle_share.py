@@ -1,0 +1,8 @@
+"""Device: share of the traced window in which no operation ran."""
+
+
+def read(run):
+    t = run.trace
+    if t is None or not t.n_devices or t.window_s <= 0:
+        return None
+    return (1.0 - t.busy_s / t.window_s) * 100.0
