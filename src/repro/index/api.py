@@ -222,6 +222,14 @@ class AccessStats:
     tiles_fetched: int = 0
     tiles_skipped: int = 0        # dead-window DMA skips (streamed sweep)
     launch_reports: int = 0       # batches with a ledger attached
+    # served-launch stages (DESIGN.md §13), always on: seconds in each
+    # stage span and bytes copied between host and device
+    prepare_s: float = 0.0        # host work before the dispatch
+    wait_s: float = 0.0           # blocked until the outputs are ready
+    fetch_s: float = 0.0          # device-to-host copies of the outputs
+    finish_s: float = 0.0         # host work after the fetch
+    h2d_bytes: int = 0            # host arrays staged to the device
+    d2h_bytes: int = 0            # outputs copied back
 
     def record(self, n_queries: int, accesses: int, launches: int) -> None:
         self.queries += int(n_queries)
@@ -254,6 +262,12 @@ class AccessStats:
         self.tiles_fetched += int(report.tiles_fetched)
         self.tiles_skipped += int(report.tiles_skipped)
         self.launch_reports += 1
+
+    def absorb_stages(self, counters: dict) -> None:
+        """Fold drained stage counters (:func:`repro.obs.trace.
+        drain_counters`) into the ledger (DESIGN.md §13)."""
+        for name, value in counters.items():
+            setattr(self, name, getattr(self, name) + value)
 
     def to_dict(self) -> dict:
         """Flat snapshot of every counter (``rung_dispatches`` stays a
@@ -920,6 +934,7 @@ class SpatialIndex:
         self.stats.record(queries.shape[0], visits.sum(), launches)
         if base_levels is not None:
             self.stats.delta_accesses += int(visits[:, base_levels:].sum())
+        self.stats.absorb_stages(_obs_trace.drain_counters())
         return RegionResult(
             hits=hits, visits_per_level=visits, base_levels=base_levels,
             launch_report=self._drain_launch_report(visits),
@@ -1019,4 +1034,5 @@ class SpatialIndex:
                 self.stats.queries += points.shape[0]
                 # fold every expanding-radius round's kernel ledger
                 self._drain_launch_report()
+        self.stats.absorb_stages(_obs_trace.drain_counters())
         return KNNResult(ids=ids, dists=dists, visits=visits)

@@ -150,8 +150,9 @@ class LaxBackend:
 
     def region(self, queries: np.ndarray):
         with _obs_trace.span("backend.lax", queries=queries.shape[0]):
-            hits, visits = self._run(jnp.asarray(queries, jnp.float32))
-            return np.asarray(hits), np.asarray(visits), 1
+            out = self._run(ops.to_device(queries, jnp.float32))
+            hits, visits = ops.fetch(*out)
+            return hits, visits, 1
 
 
 def _make_lax_sweep(schedule: LevelSchedule):
@@ -308,6 +309,7 @@ class PallasBackend:
         return cfg
 
     def _run_one(self, queries: np.ndarray, cfg):
+        """One launch; returns host ``(hits, visits, launches)``."""
         if not cfg.levels_in_grid:
             # Per-level launch plan — float32 non-streamed only (the
             # candidate grid never proposes it elsewhere); hits and
@@ -331,6 +333,7 @@ class PallasBackend:
                 self.schedule, queries, block_w=cfg.block_w,
                 interpret=self.interpret, stream=self.stream,
             )
+        hits, visits = ops.fetch(hits, visits)
         return hits, visits, 1
 
     def _run(self, queries: np.ndarray, cfg):
@@ -339,8 +342,8 @@ class PallasBackend:
             hs, vs, launches = [], [], 0
             for i in range(0, queries.shape[0], qb):
                 h, v, n = self._run_one(queries[i:i + qb], cfg)
-                hs.append(np.asarray(h))
-                vs.append(np.asarray(v))
+                hs.append(h)
+                vs.append(v)
                 launches += n
             return np.concatenate(hs), np.concatenate(vs), launches
         return self._run_one(queries, cfg)
@@ -353,7 +356,6 @@ class PallasBackend:
             if _obs_counters.collecting():
                 _obs_counters.drain()  # discard autotune-probe emissions
             hits, visits, launches = self._run(queries, cfg)
-            hits, visits = np.asarray(hits), np.asarray(visits)
         if _obs_counters.collecting():
             # The query_block chunking above emits one report per chunk;
             # re-emit them merged, stamped with the tiling actually used

@@ -29,6 +29,9 @@ import heapq
 
 import numpy as np
 
+from repro.kernels.ops import fetch, to_device
+from repro.obs import trace as _obs_trace
+
 from .trees import node_children as _node_children
 from .trees import node_mbr as _node_mbr
 
@@ -144,39 +147,50 @@ def knn_expanding(
     and :func:`knn_brute`.
 
     Returns ``(ids (Q, k), dists (Q, k), visits (Q,), rounds)``.
-    """
-    obj_mbrs = np.asarray(obj_mbrs, np.float64)
-    points = np.asarray(points, np.float64)
-    nq = points.shape[0]
-    n = obj_mbrs.shape[0]
 
-    # Initial radius from the density estimate: a square expected to hold
-    # ~k objects under a uniform spread of n objects over the data extent.
-    extent = max(
-        obj_mbrs[:, 2].max() - obj_mbrs[:, 0].min(),
-        obj_mbrs[:, 3].max() - obj_mbrs[:, 1].min(),
-        1e-6,
-    )
-    r = np.full((nq,), 0.5 * extent * np.sqrt(k / max(n, 1)) + 1e-6)
+    Building each round's rectangles and staging the epilogue are the
+    ``engine.prepare`` stage, the per-round survivor checks
+    ``engine.finish``; ``region_fn`` times its own launches.
+    """
+
+    with _obs_trace.stage("engine.prepare", "prepare_s"):
+        obj_mbrs = np.asarray(obj_mbrs, np.float64)
+        points = np.asarray(points, np.float64)
+        nq = points.shape[0]
+        n = obj_mbrs.shape[0]
+
+        # Initial radius from the density estimate: a square expected to
+        # hold ~k objects under a uniform spread of n objects over the
+        # data extent.
+        extent = max(
+            obj_mbrs[:, 2].max() - obj_mbrs[:, 0].min(),
+            obj_mbrs[:, 3].max() - obj_mbrs[:, 1].min(),
+            1e-6,
+        )
+        r = np.full((nq,), 0.5 * extent * np.sqrt(k / max(n, 1)) + 1e-6)
+
+    def probe(radius):
+        with _obs_trace.stage("engine.prepare", "prepare_s"):
+            return np.stack(
+                [points[:, 0] - radius, points[:, 1] - radius,
+                 points[:, 0] + radius, points[:, 1] + radius],
+                axis=1,
+            ).astype(np.float32)
 
     total_visits = np.zeros((nq,), np.int64)
     rounds = 0
     satisfied = np.zeros((nq,), bool)
     for _ in range(max_rounds):
-        queries = np.stack(
-            [points[:, 0] - r, points[:, 1] - r,
-             points[:, 0] + r, points[:, 1] + r],
-            axis=1,
-        ).astype(np.float32)
-        hits, visits = region_fn(queries)
+        hits, visits = region_fn(probe(r))
         rounds += 1
-        total_visits += np.asarray(visits).sum(axis=1)
-        satisfied = np.asarray(hits).sum(axis=1) >= k
+        with _obs_trace.stage("engine.finish", "finish_s"):
+            total_visits += np.asarray(visits).sum(axis=1)
+            satisfied = np.asarray(hits).sum(axis=1) >= k
+            # double only the radii still short of k survivors; satisfied
+            # points keep their radius (their result is already final-bound)
+            r = np.where(satisfied, r, r * 2.0)
         if satisfied.all():
             break
-        # double only the radii still short of k survivors; satisfied
-        # points keep their radius (their result is already final-bound)
-        r = np.where(satisfied, r, r * 2.0)
     if not satisfied.all():
         raise RuntimeError(
             f"knn radius expansion did not reach k={k} survivors "
@@ -185,28 +199,24 @@ def knn_expanding(
 
     # Confirming round: the square of radius r·√2 covers the Euclidean
     # d_k-ball (see module docstring), making the candidate set exact.
-    rf = r * _CONFIRM_MARGIN
-    queries = np.stack(
-        [points[:, 0] - rf, points[:, 1] - rf,
-         points[:, 0] + rf, points[:, 1] + rf],
-        axis=1,
-    ).astype(np.float32)
-    hits, visits = region_fn(queries)
+    hits, visits = region_fn(probe(r * _CONFIRM_MARGIN))
     rounds += 1
-    total_visits += np.asarray(visits).sum(axis=1)
+    with _obs_trace.stage("engine.finish", "finish_s"):
+        total_visits += np.asarray(visits).sum(axis=1)
 
     # Top-k distance epilogue in jnp over the surviving candidates.
-    pts = jnp.asarray(points, jnp.float32)
-    mb = jnp.asarray(obj_mbrs, jnp.float32)
-    px, py = pts[:, 0][:, None], pts[:, 1][:, None]
-    dx = jnp.maximum(jnp.maximum(mb[None, :, 0] - px, px - mb[None, :, 2]), 0.0)
-    dy = jnp.maximum(jnp.maximum(mb[None, :, 1] - py, py - mb[None, :, 3]), 0.0)
-    d = jnp.sqrt(dx * dx + dy * dy)
-    d = jnp.where(jnp.asarray(hits), d, jnp.inf)
-    neg_top, ids = lax.top_k(-d, k)
-    return (
-        np.asarray(ids, np.int32),
-        np.asarray(-neg_top, np.float32),
-        total_visits,
-        rounds,
-    )
+    with _obs_trace.stage("engine.prepare", "prepare_s"):
+        pts = to_device(points, jnp.float32)
+        mb = to_device(obj_mbrs, jnp.float32)
+        px, py = pts[:, 0][:, None], pts[:, 1][:, None]
+        dx = jnp.maximum(
+            jnp.maximum(mb[None, :, 0] - px, px - mb[None, :, 2]), 0.0)
+        dy = jnp.maximum(
+            jnp.maximum(mb[None, :, 1] - py, py - mb[None, :, 3]), 0.0)
+        d = jnp.sqrt(dx * dx + dy * dy)
+        d = jnp.where(to_device(hits), d, jnp.inf)
+        neg_top, ids = lax.top_k(-d, k)
+        dists = -neg_top
+    ids, dists = fetch(ids, dists)
+    return (np.asarray(ids, np.int32), np.asarray(dists, np.float32),
+            total_visits, rounds)

@@ -40,6 +40,8 @@ from .quantize import quantize_cm_pallas as quantize_cm_pallas  # noqa: F401
 from .quantize import quantize_rows as quantize_rows  # noqa: F401 (re-export)
 from .quantize import quantize_schedule as _quantize_schedule
 from .rmsnorm import rmsnorm as _rmsnorm
+from .transfer import fetch as fetch  # noqa: F401 (re-export)
+from .transfer import to_device as to_device  # noqa: F401 (re-export)
 
 
 def interpret_default() -> bool:

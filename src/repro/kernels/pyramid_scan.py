@@ -56,6 +56,9 @@ from repro.core.flat import (
     _overlaps,
 )
 from repro.obs import counters as _obs_counters
+from repro.obs import trace as _obs_trace
+
+from .transfer import to_device
 
 # Scoped-VMEM budget of the sweep, pair-sweep and build kernels.  The
 # compiler's default (16 MiB on v5e) is far below the chip's 128 MiB; the
@@ -759,35 +762,38 @@ def pyramid_scan(
     (pyramid schedules).  ONE kernel launch regardless of tree height.
     ``stream=True`` uses the HBM-streaming kernel (DESIGN.md §12) —
     bit-identical results, VMEM bounded by the tile/window working set.
+    Host work up to the dispatch (parent windows, staging every host
+    array of the schedule) is the ``engine.prepare`` stage.
     """
-    win_off, win_w = (None, None)
-    if stream:
-        win_off, win_w = parent_windows(
-            schedule.parent, schedule.n_real, block_w=block_w
+    with _obs_trace.stage("engine.prepare", "prepare_s"):
+        win_off, win_w = (None, None)
+        if stream:
+            win_off, win_w = parent_windows(
+                schedule.parent, schedule.n_real, block_w=block_w
+            )
+        if _obs_counters.collecting():  # side channel: eager wrappers only
+            _obs_counters.emit(_obs_counters.scan_report_float32(
+                schedule, queries, block_w=block_w, stream=stream,
+                win_off=win_off, win_w=win_w))
+        if stream:
+            win_off = to_device(win_off)
+        return _fused_search(
+            to_device(queries, jnp.float32),
+            to_device(schedule.mbr_cm),
+            to_device(schedule.parent),
+            to_device(schedule.obj_mbr),
+            to_device(schedule.obj_level),
+            to_device(schedule.obj_slot),
+            to_device(schedule.obj_id),
+            n_objects=schedule.n_objects,
+            block_w=block_w,
+            root_unconditional=schedule.root_unconditional,
+            test_object_mbr=schedule.test_object_mbr,
+            interpret=interpret,
+            stream=stream,
+            win_off=win_off,
+            win_w=win_w,
         )
-    if _obs_counters.collecting():  # side channel: eager wrappers only
-        _obs_counters.emit(_obs_counters.scan_report_float32(
-            schedule, queries, block_w=block_w, stream=stream,
-            win_off=win_off, win_w=win_w))
-    if stream:
-        win_off = jnp.asarray(win_off)
-    return _fused_search(
-        jnp.asarray(queries, jnp.float32),
-        jnp.asarray(schedule.mbr_cm),
-        jnp.asarray(schedule.parent),
-        jnp.asarray(schedule.obj_mbr),
-        jnp.asarray(schedule.obj_level),
-        jnp.asarray(schedule.obj_slot),
-        jnp.asarray(schedule.obj_id),
-        n_objects=schedule.n_objects,
-        block_w=block_w,
-        root_unconditional=schedule.root_unconditional,
-        test_object_mbr=schedule.test_object_mbr,
-        interpret=interpret,
-        stream=stream,
-        win_off=win_off,
-        win_w=win_w,
-    )
 
 
 @functools.partial(
@@ -848,36 +854,37 @@ def pyramid_scan_compact(
     """Fused region search over a :class:`QuantizedSchedule`: half the
     streamed bytes per tile, hit sets bit-identical to the float32 path;
     ``visits`` reports the compact sweep's own (conservative) accesses."""
-    win_off, win_w = (None, None)
-    if stream:
-        win_off, win_w = parent_windows(
-            qsched.parent_q, qsched.base.n_real, block_w=block_w
+    with _obs_trace.stage("engine.prepare", "prepare_s"):
+        win_off, win_w = (None, None)
+        if stream:
+            win_off, win_w = parent_windows(
+                qsched.parent_q, qsched.base.n_real, block_w=block_w
+            )
+        if _obs_counters.collecting():  # side channel: eager wrappers only
+            _obs_counters.emit(_obs_counters.scan_report_compact(
+                qsched, queries, block_w=block_w, stream=stream,
+                win_off=win_off, win_w=win_w))
+        if stream:
+            win_off = to_device(win_off)
+        return _fused_search_compact(
+            to_device(queries, jnp.float32),
+            to_device(qsched.mbr_q),
+            to_device(qsched.parent_q),
+            to_device(qsched.confirm_mbr),
+            to_device(qsched.base.obj_level),
+            to_device(qsched.base.obj_slot),
+            to_device(qsched.base.obj_id),
+            to_device(qsched.origin),
+            to_device(qsched.inv_cell),
+            n_objects=qsched.n_objects,
+            cells=qsched.cells,
+            block_w=block_w,
+            root_unconditional=qsched.base.root_unconditional,
+            interpret=interpret,
+            stream=stream,
+            win_off=win_off,
+            win_w=win_w,
         )
-    if _obs_counters.collecting():  # side channel: eager wrappers only
-        _obs_counters.emit(_obs_counters.scan_report_compact(
-            qsched, queries, block_w=block_w, stream=stream,
-            win_off=win_off, win_w=win_w))
-    if stream:
-        win_off = jnp.asarray(win_off)
-    return _fused_search_compact(
-        jnp.asarray(queries, jnp.float32),
-        jnp.asarray(qsched.mbr_q),
-        jnp.asarray(qsched.parent_q),
-        jnp.asarray(qsched.confirm_mbr),
-        jnp.asarray(qsched.base.obj_level),
-        jnp.asarray(qsched.base.obj_slot),
-        jnp.asarray(qsched.base.obj_id),
-        jnp.asarray(qsched.origin),
-        jnp.asarray(qsched.inv_cell),
-        n_objects=qsched.n_objects,
-        cells=qsched.cells,
-        block_w=block_w,
-        root_unconditional=qsched.base.root_unconditional,
-        interpret=interpret,
-        stream=stream,
-        win_off=win_off,
-        win_w=win_w,
-    )
 
 
 @functools.partial(
@@ -945,38 +952,39 @@ def pyramid_scan_compact8(
         raise ValueError(
             "pyramid_scan_compact8 needs quantize_schedule(..., upper8=True)"
         )
-    if _obs_counters.collecting():  # side channel: eager wrappers only
-        _obs_counters.emit(_obs_counters.scan_report_compact8(
-            qsched, queries, block_w=block_w))
-    split = qsched.split
-    return _fused_search_compact8(
-        jnp.asarray(queries, jnp.float32),
-        jnp.asarray(
-            qsched.mbr_q8
-            if qsched.mbr_q8 is not None
-            else np.zeros((0, 4, qsched.width), np.uint8)
-        ),
-        jnp.asarray(qsched.mbr_q[split:]),
-        jnp.asarray(qsched.parent_q),
-        jnp.asarray(qsched.confirm_mbr),
-        jnp.asarray(qsched.base.obj_level),
-        jnp.asarray(qsched.base.obj_slot),
-        jnp.asarray(qsched.base.obj_id),
-        jnp.asarray(qsched.origin),
-        jnp.asarray(qsched.inv_cell),
-        jnp.asarray(
-            qsched.inv_cell8
-            if qsched.inv_cell8 is not None
-            else qsched.inv_cell
-        ),
-        n_objects=qsched.n_objects,
-        cells=qsched.cells,
-        cells8=qsched.cells8,
-        split=split,
-        block_w=block_w,
-        root_unconditional=qsched.base.root_unconditional,
-        interpret=interpret,
-    )
+    with _obs_trace.stage("engine.prepare", "prepare_s"):
+        if _obs_counters.collecting():  # side channel: eager wrappers only
+            _obs_counters.emit(_obs_counters.scan_report_compact8(
+                qsched, queries, block_w=block_w))
+        split = qsched.split
+        return _fused_search_compact8(
+            to_device(queries, jnp.float32),
+            to_device(
+                qsched.mbr_q8
+                if qsched.mbr_q8 is not None
+                else np.zeros((0, 4, qsched.width), np.uint8)
+            ),
+            to_device(qsched.mbr_q[split:]),
+            to_device(qsched.parent_q),
+            to_device(qsched.confirm_mbr),
+            to_device(qsched.base.obj_level),
+            to_device(qsched.base.obj_slot),
+            to_device(qsched.base.obj_id),
+            to_device(qsched.origin),
+            to_device(qsched.inv_cell),
+            to_device(
+                qsched.inv_cell8
+                if qsched.inv_cell8 is not None
+                else qsched.inv_cell
+            ),
+            n_objects=qsched.n_objects,
+            cells=qsched.cells,
+            cells8=qsched.cells8,
+            split=split,
+            block_w=block_w,
+            root_unconditional=qsched.base.root_unconditional,
+            interpret=interpret,
+        )
 
 
 @functools.partial(

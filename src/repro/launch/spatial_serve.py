@@ -339,67 +339,74 @@ class SpatialServer:
         The boundary is hardened: NaN/±inf/inverted rectangles raise the
         typed :class:`repro.index.InvalidQueryError` BEFORE any of them
         can be cached or poison a padded batch's neighbours.
+
+        Validation, keys, the cache lookup, dedupe and padding are the
+        ``engine.prepare`` stage; the cache fill and unstacking after the
+        fetch are ``engine.finish``.
         """
         # lazy import: repro.index imports this module's backend wrapper,
         # so the validation helper is pulled at call time, not import time
         from repro.index.api import validate_queries
 
-        queries = validate_queries(queries, what="served queries")
-        nq = queries.shape[0]
-        if nq == 0:
-            return (
-                np.zeros((0, max(self._n_out, 1)), bool),
-                np.zeros((0, self._levels_out), np.int32),
-            )
-        self.stats.queries_served += nq
+        with _obs_trace.stage("engine.prepare", "prepare_s"):
+            queries = validate_queries(queries, what="served queries")
+            nq = queries.shape[0]
+            if nq == 0:
+                return (
+                    np.zeros((0, max(self._n_out, 1)), bool),
+                    np.zeros((0, self._levels_out), np.int32),
+                )
+            self.stats.queries_served += nq
 
-        keys = [queries[i].tobytes() for i in range(nq)]
-        fresh: dict = {}   # results computed for THIS call; immune to LRU
-        miss_rows: list[np.ndarray] = []
-        for i, k in enumerate(keys):
-            if k in fresh:  # duplicate within this batch: computed once
-                self.stats.dedup_hits += 1
-            elif k in self._cache:
-                tag, value = self._cache[k]
-                if tag == self.epoch:
-                    fresh[k] = value
-                    self._cache.move_to_end(k)
-                    self.stats.cache_hits += 1
+            keys = [queries[i].tobytes() for i in range(nq)]
+            fresh: dict = {}   # results computed for THIS call; immune to LRU
+            miss_rows: list[np.ndarray] = []
+            for i, k in enumerate(keys):
+                if k in fresh:  # duplicate within this batch: computed once
+                    self.stats.dedup_hits += 1
+                elif k in self._cache:
+                    tag, value = self._cache[k]
+                    if tag == self.epoch:
+                        fresh[k] = value
+                        self._cache.move_to_end(k)
+                        self.stats.cache_hits += 1
+                    else:
+                        # cached under an older mutation epoch: stale — drop
+                        # and recompute (epoch-tagged invalidation, §8)
+                        del self._cache[k]
+                        fresh[k] = None
+                        miss_rows.append(queries[i])
                 else:
-                    # cached under an older mutation epoch: stale — drop
-                    # and recompute (epoch-tagged invalidation, §8)
-                    del self._cache[k]
-                    fresh[k] = None
+                    fresh[k] = None  # placeholder, filled after dispatch
                     miss_rows.append(queries[i])
-            else:
-                fresh[k] = None  # placeholder, filled after dispatch
-                miss_rows.append(queries[i])
 
         if miss_rows:
             block_hits, block_visits = self._dispatch(np.stack(miss_rows))
-            j = 0
-            for k, v in fresh.items():
-                if v is None:
-                    fresh[k] = (block_hits[j], block_visits[j])
-                    self._put(k, fresh[k])
-                    j += 1
+        with _obs_trace.stage("engine.finish", "finish_s"):
+            if miss_rows:
+                j = 0
+                for k, v in fresh.items():
+                    if v is None:
+                        fresh[k] = (block_hits[j], block_visits[j])
+                        self._put(k, fresh[k])
+                        j += 1
 
-        hits = np.stack([fresh[k][0] for k in keys])
-        visits = np.stack([fresh[k][1] for k in keys])
+            hits = np.stack([fresh[k][0] for k in keys])
+            visits = np.stack([fresh[k][1] for k in keys])
         return hits, visits
 
     # ------------------------------------------------------------------
     def _dispatch(self, miss: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
         qb = self.query_block
         n = miss.shape[0]
-        pad = (-n) % qb
-        if pad:
-            # pad with never-overlapping null queries (results discarded)
-            miss = np.concatenate(
-                [miss, np.broadcast_to(NEVER_MBR, (pad, 4))], axis=0
-            )
-        blocks = miss.reshape(-1, qb, 4)
-        nb = blocks.shape[0]
+        with _obs_trace.stage("engine.prepare", "prepare_s"):
+            pad = (-n) % qb
+            if pad:
+                # pad with never-overlapping null queries (results discarded)
+                miss = np.concatenate(
+                    [miss, np.broadcast_to(NEVER_MBR, (pad, 4))], axis=0
+                )
+            blocks = miss.reshape(-1, qb, 4)
         hits, visits, launches = self._run_ladder(blocks)
         self.stats.batches_dispatched += 1
         self.stats.kernel_launches += launches
@@ -472,35 +479,29 @@ class SpatialServer:
     ) -> Tuple[np.ndarray, np.ndarray, int]:
         """One attempt on one rung; returns flat (hits, visits, launches)."""
         nb, qb, _ = blocks.shape
-        if rung == "pallas":
-            n_dev = jax.device_count()
-            if self._pmapped is not None and nb % n_dev == 0:
-                sharded = blocks.reshape(n_dev, nb // n_dev, qb, 4)
-                hits, visits = self._pmapped(
-                    jnp.asarray(sharded), *self._arrays
-                )
-            else:
-                hits, visits = self._vmapped(
-                    jnp.asarray(blocks), *self._arrays
-                )
-            return (
-                np.asarray(hits).reshape(nb * qb, -1),
-                np.asarray(visits).reshape(nb * qb, -1),
-                nb,
-            )
-        if rung == "lax":
-            if self._vmapped_lax is None:
-                self._vmapped_lax = jax.jit(
-                    jax.vmap(self._inner_lax, in_axes=self._batch_axes)
-                )
-            hits, visits = self._vmapped_lax(
-                jnp.asarray(blocks), *self._arrays
-            )
-            return (
-                np.asarray(hits).reshape(nb * qb, -1),
-                np.asarray(visits).reshape(nb * qb, -1),
-                nb,
-            )
+        if rung in ("pallas", "lax"):
+            with _obs_trace.stage("engine.prepare", "prepare_s"):
+                n_dev = jax.device_count()
+                if rung == "lax":
+                    if self._vmapped_lax is None:
+                        self._vmapped_lax = jax.jit(
+                            jax.vmap(self._inner_lax,
+                                     in_axes=self._batch_axes)
+                        )
+                    hits, visits = self._vmapped_lax(
+                        ops.to_device(blocks), *self._arrays
+                    )
+                elif self._pmapped is not None and nb % n_dev == 0:
+                    sharded = blocks.reshape(n_dev, nb // n_dev, qb, 4)
+                    hits, visits = self._pmapped(
+                        ops.to_device(sharded), *self._arrays
+                    )
+                else:
+                    hits, visits = self._vmapped(
+                        ops.to_device(blocks), *self._arrays
+                    )
+            hits, visits = ops.fetch(hits, visits)
+            return hits.reshape(nb * qb, -1), visits.reshape(nb * qb, -1), nb
         # host: pure numpy, zero device launches
         if self._np_arrays is None:
             self._np_arrays = tuple(np.asarray(a) for a in self._arrays)

@@ -5,7 +5,9 @@ numbers the benches disclose.
 
 * :mod:`repro.obs.trace` — flight-recorder spans with Chrome/Perfetto
   ``trace.json`` export, threaded through façade → backend → kernel and
-  the serving/durability paths.
+  the serving/durability paths; stage spans inside a served launch feed
+  always-on counters and, while a JAX profiler session collects, the
+  profiler's own trace.
 * :mod:`repro.obs.counters` — the per-launch kernel byte/tile ledger
   (:class:`~repro.obs.counters.LaunchReport`) and the §12 bench's
   accounting functions, now shared by bench and production.
@@ -27,11 +29,13 @@ from repro.obs.trace import (
     Tracer,
     counter,
     disable,
+    drain_counters,
     enable,
     get_tracer,
     instant,
     set_tracer,
     span,
+    stage,
 )
 
 __all__ = [
@@ -42,6 +46,7 @@ __all__ = [
     "counter",
     "counters",
     "disable",
+    "drain_counters",
     "enable",
     "get_tracer",
     "instant",
@@ -49,5 +54,6 @@ __all__ = [
     "metrics",
     "set_tracer",
     "span",
+    "stage",
     "trace",
 ]

@@ -9,29 +9,55 @@ kinds into a bounded ring buffer:
   containment per thread, so spans survive exceptions — ``__exit__``
   always runs and stamps the error type into ``args``.
 * ``instant(name, **args)`` — a point event ("ph": "i"), used for
-  degradation-rung transitions, deadline trips, enqueue marks.
+  degradation-rung transitions and deadline trips.
 * ``counter(name, **values)`` — a counter track ("ph": "C"), used for
   span-less overload accounting (shed/queued requests).
 
 The disabled fast path is a single attribute check returning a shared
 no-op span object — no allocation, no clock read — so production code
-can leave instrumentation inline (the <2% overhead budget is enforced
-by ``bench_obs`` + the CI perf guard).  The ring buffer (default 64k
-events) makes the tracer a flight recorder: always safe to leave on,
-oldest events are dropped and counted in :attr:`Tracer.dropped`.
+can leave instrumentation inline; its cost is measured on the chip, by
+the benchmark's untraced runs against its traced ones (PERF.md §6).  The
+ring buffer (default 64k events) makes the tracer a flight recorder:
+always safe to leave on, oldest events are dropped and counted in
+:attr:`Tracer.dropped`.
 
 Timestamps are microseconds on ``time.monotonic`` relative to tracer
 creation, which is exactly what the Chrome trace-event format expects.
+
+**Stage spans.** ``stage(name, counter)`` times one step inside a served
+launch (``engine.prepare`` / ``engine.wait`` / ``engine.fetch`` /
+``engine.finish``) and has three sinks:
+
+* always: its ``time.perf_counter`` seconds are added to the named
+  process counter (``prepare_s``, ...), as :func:`add` adds byte counts
+  (``h2d_bytes``, ``d2h_bytes``); the façade folds them into the
+  tenant's ``AccessStats`` (:func:`drain_counters`), so they are
+  operator metrics that ``SpatialIndex.metrics()`` exports;
+* while the tracer is enabled: an "X" event in the ring buffer, as
+  ``span()`` records;
+* while a JAX profiler session collects: a ``jax.profiler.TraceAnnotation``
+  of the same name, so the stage sits on the profiler's host line, on
+  the device trace's clock, beside the device ops it waits for.
+
+Only stages reach the profiler.  A trace reduction names an idle device
+gap by the host annotation that overlaps it most; a layer span
+(``serve.launch``, ``index.*``, ``backend.*``) encloses every stage of
+its launch and would always win, hiding the step beneath it.  With both
+sinks off a stage is a shared object per name: two clock reads and an
+add, no span object and no annotation.  A stage does not nest inside
+another stage, so the stage seconds of a launch add up to at most its
+wall time.
 """
 
 from __future__ import annotations
 
 import json
 import os
+import sys
 import threading
 import time
 from collections import deque
-from typing import Any, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional
 
 
 class _NullSpan:
@@ -220,6 +246,101 @@ def instant(name: str, **args: Any) -> None:
 
 def counter(name: str, **values: Any) -> None:
     _TRACER.counter(name, **values)
+
+
+# -- stage spans: always-on counters, ring buffer, profiler --------------
+# Process counters the stages feed until the façade drains them.
+_COUNTERS: Dict[str, float] = {}
+# TraceMe.is_enabled and TraceAnnotation, resolved once jax is imported.
+_collecting: Optional[Callable[[], bool]] = None
+_Annotation = None
+
+
+def add(counter: str, value: float) -> None:
+    """Add ``value`` to a stage counter (e.g. ``h2d_bytes``)."""
+    _COUNTERS[counter] = _COUNTERS.get(counter, 0) + value
+
+
+def drain_counters() -> Dict[str, float]:
+    """The stage counters accumulated since the last drain; resets them."""
+    global _COUNTERS
+    out, _COUNTERS = _COUNTERS, {}
+    return out
+
+
+def profiler_collecting() -> bool:
+    """True while a JAX profiler session collects.  Without jax imported
+    no session can exist, and this imports none."""
+    global _collecting, _Annotation
+    if _collecting is None:
+        if "jax" not in sys.modules:
+            return False
+        try:
+            from jax._src.lib import _profiler
+            from jax.profiler import TraceAnnotation
+        except ImportError:
+            _collecting = lambda: False
+        else:
+            _collecting = _profiler.TraceMe.is_enabled
+            _Annotation = TraceAnnotation
+    return _collecting()
+
+
+class Stage:
+    """A stage with only the counter sink: shared per name while off."""
+
+    __slots__ = ("name", "counter", "_t0")
+
+    def __init__(self, name: str, counter: str):
+        self.name = name
+        self.counter = counter
+        self._t0 = None
+
+    def __enter__(self) -> "Stage":
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, et, ev, tb) -> bool:
+        add(self.counter, time.perf_counter() - self._t0)
+        self._t0 = None
+        return False
+
+
+class _LiveStage(Stage):
+    """A stage while the tracer is on or the profiler collects."""
+
+    __slots__ = ("_span", "_ann")
+
+    def __enter__(self) -> "_LiveStage":
+        self._span = span(self.name)
+        self._span.__enter__()
+        self._ann = _Annotation(self.name) if profiler_collecting() else None
+        if self._ann is not None:
+            self._ann.__enter__()
+        return super().__enter__()
+
+    def __exit__(self, et, ev, tb) -> bool:
+        super().__exit__(et, ev, tb)
+        if self._ann is not None:
+            self._ann.__exit__(et, ev, tb)
+        self._span.__exit__(et, ev, tb)
+        return False
+
+
+_STAGES: Dict[str, Stage] = {}
+
+
+def stage(name: str, counter: str) -> Stage:
+    """A stage span adding its seconds to ``counter``; see the module
+    docstring.  A name feeds one counter, the one its first call gave."""
+    if _TRACER.enabled or profiler_collecting():
+        return _LiveStage(name, counter)
+    st = _STAGES.get(name)
+    if st is None:
+        st = _STAGES[name] = Stage(name, counter)
+    elif st._t0 is not None:  # open already: another thread holds it
+        return Stage(name, counter)
+    return st
 
 
 if os.environ.get("REPRO_TRACE") == "1":  # opt-in via env for CLIs

@@ -211,8 +211,6 @@ class ServingFrontEnd:
             self.tenants[tenant].stats.queued_queries += 1
             _obs_trace.counter("serve.queued_overload",
                                queued=self.telemetry.queued_overload)
-        _obs_trace.instant("serve.enqueue", tenant=tenant, kind=kind,
-                           slo=cls.name, seq=req.seq)
         self.queue.add(req)
         return req
 
@@ -266,20 +264,25 @@ class ServingFrontEnd:
             if key[0] == "rect":
                 rects = np.stack([r.payload for r in batch])
                 res = rt.index.region(rects)
-                for i, req in enumerate(batch):
-                    if req.kind == "count":
-                        req.result = int(res.hits[i].sum())
-                    else:
-                        req.result = Answer(
-                            hits=res.hits[i], visits=res.visits_per_level[i]
-                        )
-                    self._complete(req)
+                with _obs_trace.stage("engine.finish", "finish_s"):
+                    for i, req in enumerate(batch):
+                        if req.kind == "count":
+                            req.result = int(res.hits[i].sum())
+                        else:
+                            req.result = Answer(
+                                hits=res.hits[i],
+                                visits=res.visits_per_level[i],
+                            )
+                        self._complete(req)
             else:
                 pts = np.stack([r.payload for r in batch])
                 res = rt.index.knn(pts, k=key[2])
-                for i, req in enumerate(batch):
-                    req.result = (res.ids[i], res.dists[i])
-                    self._complete(req)
+                with _obs_trace.stage("engine.finish", "finish_s"):
+                    for i, req in enumerate(batch):
+                        req.result = (res.ids[i], res.dists[i])
+                        self._complete(req)
+        # the answers' hand-out joins the index's own stages
+        rt.stats.absorb_stages(_obs_trace.drain_counters())
         done = self.clock()
         self.queue.observe_service(key, done - t_launch)
         self.telemetry.batches += 1

@@ -12,13 +12,21 @@ The contract under test:
    bench computes — ``RegionResult.launch_report.bytes_streamed`` is
    bit-for-bit the bench's "bytes-streamed-skip-uint16" row;
 4. the metrics registry renders well-formed Prometheus text and JSON,
-   with per-tenant latency quantiles.
+   with per-tenant latency quantiles;
+5. stage spans feed always-on counters, the ring buffer only while the
+   tracer is on, and the JAX profiler only while a session collects;
+   across a served launch the counters equal the bytes staged and
+   fetched and the kNN rounds run.
 """
 
 from __future__ import annotations
 
+import glob
 import json
 import re
+import subprocess
+import sys
+import time
 import warnings
 
 import numpy as np
@@ -128,6 +136,196 @@ class TestSpans:
         assert names["s"]["args"]["rows"] == 7
         assert names["mark"]["ph"] == "i"
         assert names["mark"]["args"] == {"k": 1}
+
+
+# ---------------------------------------------------------------------------
+# stage spans
+# ---------------------------------------------------------------------------
+
+
+def _host_line_names(log_dir):
+    """Event names on the profiler's host line of the main thread."""
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(f"{log_dir}/plugins/profile/*/*.xplane.pb")
+    names = set()
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                if "/" not in line.name:  # worker threads carry "/<tid>"
+                    names |= {ev.name for ev in line.events}
+    return names
+
+
+class _Refused:
+    def __init__(self, *a, **k):
+        raise AssertionError("the off path built a span or annotation")
+
+
+class TestStages:
+    def test_off_path_shares_one_object_and_builds_nothing(
+            self, monkeypatch):
+        old = obs_trace.get_tracer()
+        obs_trace.set_tracer(obs_trace.Tracer())
+        try:
+            assert not obs_trace.profiler_collecting()
+            monkeypatch.setattr(obs_trace, "Span", _Refused)
+            monkeypatch.setattr(obs_trace, "_Annotation", _Refused)
+            import jax.profiler
+            monkeypatch.setattr(jax.profiler, "TraceAnnotation", _Refused)
+            obs_trace.drain_counters()
+            a = obs_trace.stage("test.off", "off_s")
+            assert type(a) is obs_trace.Stage
+            assert obs_trace.stage("test.off", "off_s") is a
+            with a:
+                # held open: a second entrant gets an object of its own
+                b = obs_trace.stage("test.off", "off_s")
+                assert b is not a and type(b) is obs_trace.Stage
+                with b:
+                    pass
+            assert obs_trace.stage("test.off", "off_s") is a
+            obs_trace.add("off_bytes", 7)
+            got = obs_trace.drain_counters()
+            assert set(got) == {"off_s", "off_bytes"}
+            assert got["off_s"] >= 0 and got["off_bytes"] == 7
+            assert obs_trace.get_tracer().events() == []
+            assert obs_trace.drain_counters() == {}
+        finally:
+            obs_trace.set_tracer(old)
+
+    def test_tracer_on_records_an_x_event(self, tracer):
+        obs_trace.drain_counters()
+        with pytest.raises(KeyError):
+            with obs_trace.stage("engine.prepare", "prepare_s"):
+                raise KeyError("x")
+        (e,) = tracer.events()
+        assert e["name"] == "engine.prepare" and e["ph"] == "X"
+        assert e["args"]["error"] == "KeyError"
+        assert obs_trace.drain_counters()["prepare_s"] >= 0
+
+    def test_only_stages_reach_a_profiler_session(self, tracer, tmp_path):
+        import jax
+        import jax.numpy as jnp
+
+        obs_trace.drain_counters()
+        with jax.profiler.trace(str(tmp_path)):
+            assert obs_trace.profiler_collecting()
+            with obs_trace.span("layer.span"):
+                with obs_trace.stage("engine.prepare", "prepare_s"):
+                    x = jnp.arange(8) + 1
+                with obs_trace.stage("engine.wait", "wait_s"):
+                    x.block_until_ready()
+        assert not obs_trace.profiler_collecting()
+        names = _host_line_names(tmp_path)
+        assert {"engine.prepare", "engine.wait"} <= names
+        assert "layer.span" not in names
+        assert {"engine.prepare", "engine.wait", "layer.span"} <= {
+            e["name"] for e in tracer.events()}
+        assert set(obs_trace.drain_counters()) == {"prepare_s", "wait_s"}
+
+    def test_obs_imports_and_stages_without_jax(self):
+        code = (
+            "import sys\n"
+            "sys.modules['jax'] = sys.modules['jaxlib'] = None\n"
+            "from repro import obs\n"
+            "with obs.stage('engine.prepare', 'prepare_s'):\n"
+            "    pass\n"
+            "assert set(obs.drain_counters()) == {'prepare_s'}\n"
+        )
+        out = subprocess.run([sys.executable, "-c", code],
+                             capture_output=True, text=True)
+        assert out.returncode == 0, out.stderr
+
+
+STAGE_FIELDS = ("prepare_s", "wait_s", "fetch_s", "finish_s")
+TENANTS = {
+    "pallas": {"structure": "pyramid", "backend": "pallas",
+               "build": "device",
+               "backend_opts": {"stream": True, "autotune": "off"}},
+    "serve": {"structure": "mqr", "backend": "serve"},
+}
+
+
+def _front(kind):
+    data = np.asarray(datasets.uniform_squares(240, seed=61), np.float32)
+    front = ServingFrontEnd.build(
+        {"query_block": 4, "tenants": [dict(TENANTS[kind], name="t")]},
+        {"t": data})
+    return front, front.tenants["t"], data
+
+
+def _timed_launch(front, submit):
+    """One launch of the front end; returns (stats delta, wall seconds)."""
+    rt = front.tenants["t"]
+    obs_trace.drain_counters()
+    before = rt.stats.to_dict()
+    tickets = submit()
+    t0 = time.perf_counter()
+    assert front.drain() == 1
+    wall = time.perf_counter() - t0
+    assert all(t.status == "done" for t in tickets)
+    return rt.stats.diff(before), wall
+
+
+class TestLaunchStages:
+    @pytest.mark.parametrize("kind", sorted(TENANTS))
+    def test_region_launch_counts_its_copies(self, kind):
+        front, rt, data = _front(kind)
+        warm = datasets.region_queries(data, 4, seed=62)
+        queries = datasets.region_queries(data, 4, seed=63)
+        for qs in (warm, queries):
+            delta, wall = _timed_launch(front, lambda: [
+                front.submit("t", "region", q) for q in qs])
+        n, levels = rt.index.n_objects, rt.index.schedule.levels
+        if kind == "pallas":
+            sched = rt.index.schedule
+            win_off, _ = ops.parent_windows(sched.parent, sched.n_real,
+                                            block_w=128)
+            staged = [queries.astype(np.float32), sched.mbr_cm,
+                      sched.parent, sched.obj_mbr, sched.obj_level,
+                      sched.obj_slot, sched.obj_id, win_off]
+            rows = queries.shape[0]
+        else:
+            # the server's padded query block; its schedule stays resident
+            rows = rt.index._backend.server.query_block
+            staged = [np.zeros((1, rows, 4), np.float32)]
+        # 64-bit host arrays go to the device as 32-bit (x64 off)
+        assert delta["h2d_bytes"] == sum(
+            a.size * min(a.dtype.itemsize, 4) for a in staged)
+        assert delta["d2h_bytes"] == rows * n + rows * levels * 4
+        assert all(delta[f] > 0 for f in STAGE_FIELDS)
+        assert sum(delta[f] for f in STAGE_FIELDS) <= wall
+
+    @pytest.mark.parametrize("kind", sorted(TENANTS))
+    def test_knn_launch_counts_its_rounds(self, kind, monkeypatch):
+        from repro.index import knn as knn_mod
+
+        real = knn_mod.knn_expanding
+        seen = []
+
+        def counted(region_fn, *args, **kw):
+            calls = []
+
+            def fn(qs):
+                calls.append(qs.shape[0])
+                return region_fn(qs)
+
+            out = real(fn, *args, **kw)
+            seen.append((out[3], len(calls)))
+            return out
+
+        monkeypatch.setattr(knn_mod, "knn_expanding", counted)
+        front, rt, data = _front(kind)
+        points = data[:4, :2] + 0.5
+        for pts in (points, points + 3.0):
+            delta, wall = _timed_launch(front, lambda: [
+                front.submit("t", "knn", p, k=5) for p in pts])
+        rounds, calls = seen[-1]
+        assert rounds == calls >= 2   # radius rounds + the confirming one
+        assert delta["knn_rounds"] == rounds
+        assert delta["h2d_bytes"] > 0 and delta["d2h_bytes"] > 0
+        assert all(delta[f] > 0 for f in STAGE_FIELDS)
+        assert sum(delta[f] for f in STAGE_FIELDS) <= wall
 
 
 # ---------------------------------------------------------------------------
@@ -386,6 +584,19 @@ class TestMetrics:
         doc = reg.to_json()
         names = {m["name"] for m in doc["metrics"]}
         assert "repro_index_queries" in names
+
+    def test_index_metrics_export_the_stage_counters(self):
+        idx, queries = _index(autotune="off")
+        idx.region(queries)
+        text = idx.metrics(tenant="t0").to_prometheus()
+        _check_prometheus(text)
+        for field in STAGE_FIELDS + ("h2d_bytes", "d2h_bytes"):
+            assert f"# TYPE repro_index_{field} counter" in text
+            m = re.search(rf'^repro_index_{field}{{tenant="t0"}} (\S+)$',
+                          text, re.M)
+            # a bare pallas index has no host work after its fetch; the
+            # front end's hand-out is what feeds finish_s
+            assert m and (float(m.group(1)) > 0) == (field != "finish_s")
 
     def test_front_end_metrics_with_per_tenant_quantiles(self):
         data = np.asarray(
