@@ -229,6 +229,7 @@ class AccessStats:
     fetch_s: float = 0.0          # device-to-host copies of the outputs
     finish_s: float = 0.0         # host work after the fetch
     h2d_bytes: int = 0            # host arrays staged to the device
+    schedule_stagings: int = 0    # schedules copied whole to the device
     d2h_bytes: int = 0            # outputs copied back
 
     def record(self, n_queries: int, accesses: int, launches: int) -> None:

@@ -229,6 +229,11 @@ class PallasBackend:
     any explicit ``block_w``/``query_block``) pins the fixed
     configuration.  Winners are cached in ``BuildArtifacts.tuned`` keyed
     by shape, so ``with_backend`` twins reuse the measurement.
+
+    The schedule is staged to the device on the first launch (the
+    autotune probe or a warm-up) and stays there, with its parent-window
+    plans, for the adapter's lifetime; a merge builds a new adapter
+    (DESIGN.md §12).
     """
 
     def __init__(self, artifacts, *, block_w: int | None = None,
@@ -266,6 +271,7 @@ class PallasBackend:
         if self._tuned is None:
             self._tuned = {}
         self._refusals = getattr(artifacts, "tune_refusals", {})
+        self._staged = None  # the schedule on the device, from launch 1
 
     def _config(self, queries: np.ndarray):
         from repro.kernels.autotune import (
@@ -318,19 +324,24 @@ class PallasBackend:
                 self.schedule, queries, block_w=cfg.block_w
             )
             return hits, visits, n_launches
+        if self._staged is None:
+            self._staged = ops.stage_schedule(
+                self.schedule if self.qschedule is None else self.qschedule,
+                self.precision,
+            )
         if self.precision == "compact":
             hits, visits = ops.pyramid_scan_compact(
-                self.qschedule, queries, block_w=cfg.block_w,
+                self._staged, queries, block_w=cfg.block_w,
                 interpret=self.interpret, stream=self.stream,
             )
         elif self.precision == "compact8":
             hits, visits = ops.pyramid_scan_compact8(
-                self.qschedule, queries, block_w=cfg.block_w,
+                self._staged, queries, block_w=cfg.block_w,
                 interpret=self.interpret,
             )
         else:
             hits, visits = ops.pyramid_scan(
-                self.schedule, queries, block_w=cfg.block_w,
+                self._staged, queries, block_w=cfg.block_w,
                 interpret=self.interpret, stream=self.stream,
             )
         hits, visits = ops.fetch(hits, visits)

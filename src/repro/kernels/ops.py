@@ -35,6 +35,7 @@ from .pyramid_scan import per_level_region_search as _per_level
 from .pyramid_scan import pyramid_scan as _pyramid_scan
 from .pyramid_scan import pyramid_scan_compact as _pyramid_scan_compact
 from .pyramid_scan import pyramid_scan_compact8 as _pyramid_scan_compact8
+from .pyramid_scan import stage_schedule as stage_schedule  # noqa: F401
 from .quantize import grid_params as grid_params  # noqa: F401 (re-export)
 from .quantize import quantize_cm_pallas as quantize_cm_pallas  # noqa: F401
 from .quantize import quantize_rows as quantize_rows  # noqa: F401 (re-export)
@@ -264,7 +265,8 @@ def pyramid_scan_compact(qsched, queries, *, block_w: int = 128,
     pass: hit sets bit-identical to :func:`pyramid_scan` at ~half the
     streamed bytes per query; ``visits`` reports the compact sweep's own
     conservative access counts (DESIGN.md §7).  ``stream=True`` runs the
-    HBM-streaming sweep (DESIGN.md §12)."""
+    HBM-streaming sweep (DESIGN.md §12).  ``qsched`` may be staged once
+    with ``stage_schedule(qsched, "compact")``."""
     if interpret is None:
         interpret = interpret_default()
     return _pyramid_scan_compact(
@@ -277,8 +279,9 @@ def pyramid_scan_compact8(qsched, queries, *, block_w: int = 128,
     """Hierarchical compact region search (DESIGN.md §12): coarse uint8
     tiles gate the upper levels, uint16 tiles the lower, and the exact
     float32 confirming pass keeps hit sets bit-identical to
-    :func:`pyramid_scan`.  Needs ``quantize_schedule(..., upper8=True)``;
-    upper-level streamed bytes drop ~2x vs the uint16 form."""
+    :func:`pyramid_scan`.  Needs ``quantize_schedule(..., upper8=True)``
+    (or its ``stage_schedule(qsched, "compact8")`` form); upper-level
+    streamed bytes drop ~2x vs the uint16 form."""
     if interpret is None:
         interpret = interpret_default()
     return _pyramid_scan_compact8(
@@ -366,7 +369,10 @@ def pyramid_scan(schedule, queries, *, block_w: int = 128,
     ``interpret=None`` follows :func:`interpret_default`.  ``stream=True``
     runs the HBM-streaming double-buffered sweep (DESIGN.md §12): MBR
     tiles stay in HBM and are DMA'd through a two-slot VMEM buffer, so
-    VMEM residency no longer bounds the schedule width."""
+    VMEM residency no longer bounds the schedule width.  ``schedule`` is
+    a host ``LevelSchedule`` (staged for this call) or its
+    :func:`stage_schedule` form, which stays on the device across calls
+    and plans each ``block_w``'s parent windows once."""
     if interpret is None:
         interpret = interpret_default()
     return _pyramid_scan(

@@ -37,6 +37,7 @@ that are bit-identical to the host pointer search / ``bulk.pyramid_search``
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import Tuple
 
@@ -746,8 +747,109 @@ def _fused_search(
     )
 
 
+@dataclasses.dataclass(frozen=True, eq=False)
+class StagedSchedule:
+    """A schedule with its arrays on the device (DESIGN.md §12).
+
+    Built by :func:`stage_schedule`.  ``arrays`` are the schedule
+    operands of the fused search of ``precision`` in its argument order,
+    ``statics`` its static arguments (``n_objects``,
+    ``root_unconditional``, ``test_object_mbr`` or the grid's cells).
+    ``source`` is the host schedule it was staged from: the streamed
+    sweep's parent windows are planned from its parents once per tile
+    width (:meth:`windows`), and the eager launch report reads it.  A
+    holder that keeps this form across launches copies only its queries
+    to the device per launch.
+    """
+
+    source: LevelSchedule | QuantizedSchedule
+    precision: str
+    arrays: Tuple[jax.Array, ...]
+    statics: dict
+    _windows: dict = dataclasses.field(default_factory=dict, repr=False)
+
+    def windows(self, block_w: int) -> Tuple[jax.Array, int]:
+        """``(win_off on the device, win_w)`` of :func:`parent_windows`
+        at ``block_w``, planned on the first call for that width."""
+        plan = self._windows.get(block_w)
+        if plan is None:
+            src = self.source
+            if self.precision == "float32":
+                parent, n_real = src.parent, src.n_real
+            else:
+                parent, n_real = src.parent_q, src.base.n_real
+            win_off, win_w = parent_windows(parent, n_real, block_w=block_w)
+            plan = self._windows[block_w] = (to_device(win_off), win_w)
+        return plan
+
+
+def stage_schedule(schedule, precision: str = "float32") -> StagedSchedule:
+    """Copy a host schedule's arrays to the device once.
+
+    ``schedule`` is a :class:`LevelSchedule` for ``precision="float32"``
+    and a :class:`QuantizedSchedule` for ``"compact"`` and ``"compact8"``
+    (the latter from ``quantize_schedule(..., upper8=True)``).  The copy
+    is an ``engine.prepare`` stage; its bytes go to ``h2d_bytes`` and
+    each call adds 1 to ``schedule_stagings`` (DESIGN.md §13).
+    """
+    if precision == "float32":
+        arrays = (schedule.mbr_cm, schedule.parent, schedule.obj_mbr,
+                  schedule.obj_level, schedule.obj_slot, schedule.obj_id)
+        statics = dict(n_objects=schedule.n_objects,
+                       root_unconditional=schedule.root_unconditional,
+                       test_object_mbr=schedule.test_object_mbr)
+    elif precision in ("compact", "compact8"):
+        base = schedule.base
+        objs = (schedule.confirm_mbr, base.obj_level, base.obj_slot,
+                base.obj_id, schedule.origin, schedule.inv_cell)
+        statics = dict(n_objects=schedule.n_objects, cells=schedule.cells,
+                       root_unconditional=base.root_unconditional)
+        if precision == "compact":
+            arrays = (schedule.mbr_q, schedule.parent_q) + objs
+        else:
+            if not schedule.hierarchical and schedule.levels > 1:
+                raise ValueError(
+                    "pyramid_scan_compact8 needs quantize_schedule(..., "
+                    "upper8=True)"
+                )
+            split = schedule.split
+            mbr_q8 = schedule.mbr_q8
+            if mbr_q8 is None:
+                mbr_q8 = np.zeros((0, 4, schedule.width), np.uint8)
+            inv_cell8 = schedule.inv_cell8
+            if inv_cell8 is None:
+                inv_cell8 = schedule.inv_cell
+            arrays = ((mbr_q8, schedule.mbr_q[split:], schedule.parent_q)
+                      + objs + (inv_cell8,))
+            statics.update(cells8=schedule.cells8, split=split)
+    else:
+        raise ValueError(
+            f"unknown precision {precision!r}; expected 'float32', "
+            f"'compact' or 'compact8'"
+        )
+    with _obs_trace.stage("engine.prepare", "prepare_s"):
+        staged = StagedSchedule(
+            schedule, precision, tuple(to_device(a) for a in arrays), statics
+        )
+    _obs_trace.add("schedule_stagings", 1)
+    return staged
+
+
+def _as_staged(schedule, precision: str) -> StagedSchedule:
+    """``schedule`` itself if already staged for ``precision``, else a
+    staging of it for this one call."""
+    if not isinstance(schedule, StagedSchedule):
+        return stage_schedule(schedule, precision)
+    if schedule.precision != precision:
+        raise ValueError(
+            f"schedule staged for precision {schedule.precision!r}, "
+            f"not {precision!r}"
+        )
+    return schedule
+
+
 def pyramid_scan(
-    schedule: LevelSchedule,
+    schedule: LevelSchedule | StagedSchedule,
     queries,
     *,
     block_w: int = 128,
@@ -762,33 +864,25 @@ def pyramid_scan(
     (pyramid schedules).  ONE kernel launch regardless of tree height.
     ``stream=True`` uses the HBM-streaming kernel (DESIGN.md §12) —
     bit-identical results, VMEM bounded by the tile/window working set.
-    Host work up to the dispatch (parent windows, staging every host
-    array of the schedule) is the ``engine.prepare`` stage.
+
+    ``schedule`` is a host :class:`LevelSchedule`, staged to the device
+    for this call alone, or its :func:`stage_schedule` form, which a
+    caller keeps across launches: then the ``engine.prepare`` stage
+    stages only the queries, and plans the parent windows only on the
+    first streamed launch at each ``block_w``.
     """
+    staged = _as_staged(schedule, "float32")
     with _obs_trace.stage("engine.prepare", "prepare_s"):
-        win_off, win_w = (None, None)
-        if stream:
-            win_off, win_w = parent_windows(
-                schedule.parent, schedule.n_real, block_w=block_w
-            )
+        win_off, win_w = staged.windows(block_w) if stream else (None, None)
         if _obs_counters.collecting():  # side channel: eager wrappers only
             _obs_counters.emit(_obs_counters.scan_report_float32(
-                schedule, queries, block_w=block_w, stream=stream,
+                staged.source, queries, block_w=block_w, stream=stream,
                 win_off=win_off, win_w=win_w))
-        if stream:
-            win_off = to_device(win_off)
         return _fused_search(
             to_device(queries, jnp.float32),
-            to_device(schedule.mbr_cm),
-            to_device(schedule.parent),
-            to_device(schedule.obj_mbr),
-            to_device(schedule.obj_level),
-            to_device(schedule.obj_slot),
-            to_device(schedule.obj_id),
-            n_objects=schedule.n_objects,
+            *staged.arrays,
+            **staged.statics,
             block_w=block_w,
-            root_unconditional=schedule.root_unconditional,
-            test_object_mbr=schedule.test_object_mbr,
             interpret=interpret,
             stream=stream,
             win_off=win_off,
@@ -844,7 +938,7 @@ def _fused_search_compact(
 
 
 def pyramid_scan_compact(
-    qsched: QuantizedSchedule,
+    qsched: QuantizedSchedule | StagedSchedule,
     queries,
     *,
     block_w: int = 128,
@@ -853,33 +947,21 @@ def pyramid_scan_compact(
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Fused region search over a :class:`QuantizedSchedule`: half the
     streamed bytes per tile, hit sets bit-identical to the float32 path;
-    ``visits`` reports the compact sweep's own (conservative) accesses."""
+    ``visits`` reports the compact sweep's own (conservative) accesses.
+    ``qsched`` may be its ``stage_schedule(qsched, "compact")`` form, as
+    in :func:`pyramid_scan`."""
+    staged = _as_staged(qsched, "compact")
     with _obs_trace.stage("engine.prepare", "prepare_s"):
-        win_off, win_w = (None, None)
-        if stream:
-            win_off, win_w = parent_windows(
-                qsched.parent_q, qsched.base.n_real, block_w=block_w
-            )
+        win_off, win_w = staged.windows(block_w) if stream else (None, None)
         if _obs_counters.collecting():  # side channel: eager wrappers only
             _obs_counters.emit(_obs_counters.scan_report_compact(
-                qsched, queries, block_w=block_w, stream=stream,
+                staged.source, queries, block_w=block_w, stream=stream,
                 win_off=win_off, win_w=win_w))
-        if stream:
-            win_off = to_device(win_off)
         return _fused_search_compact(
             to_device(queries, jnp.float32),
-            to_device(qsched.mbr_q),
-            to_device(qsched.parent_q),
-            to_device(qsched.confirm_mbr),
-            to_device(qsched.base.obj_level),
-            to_device(qsched.base.obj_slot),
-            to_device(qsched.base.obj_id),
-            to_device(qsched.origin),
-            to_device(qsched.inv_cell),
-            n_objects=qsched.n_objects,
-            cells=qsched.cells,
+            *staged.arrays,
+            **staged.statics,
             block_w=block_w,
-            root_unconditional=qsched.base.root_unconditional,
             interpret=interpret,
             stream=stream,
             win_off=win_off,
@@ -938,7 +1020,7 @@ def _fused_search_compact8(
 
 
 def pyramid_scan_compact8(
-    qsched: QuantizedSchedule,
+    qsched: QuantizedSchedule | StagedSchedule,
     queries,
     *,
     block_w: int = 128,
@@ -946,43 +1028,19 @@ def pyramid_scan_compact8(
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Fused region search over the hierarchical (uint8 upper-level) form
     of a :class:`QuantizedSchedule` — ``quantize_schedule(..., upper8=
-    True)``.  Hit sets bit-identical to every other precision; upper-level
-    tiles stream at 1 byte per coordinate (DESIGN.md §12)."""
-    if not qsched.hierarchical and qsched.levels > 1:
-        raise ValueError(
-            "pyramid_scan_compact8 needs quantize_schedule(..., upper8=True)"
-        )
+    True)``, or its ``stage_schedule(qsched, "compact8")`` form.  Hit
+    sets bit-identical to every other precision; upper-level tiles
+    stream at 1 byte per coordinate (DESIGN.md §12)."""
+    staged = _as_staged(qsched, "compact8")
     with _obs_trace.stage("engine.prepare", "prepare_s"):
         if _obs_counters.collecting():  # side channel: eager wrappers only
             _obs_counters.emit(_obs_counters.scan_report_compact8(
-                qsched, queries, block_w=block_w))
-        split = qsched.split
+                staged.source, queries, block_w=block_w))
         return _fused_search_compact8(
             to_device(queries, jnp.float32),
-            to_device(
-                qsched.mbr_q8
-                if qsched.mbr_q8 is not None
-                else np.zeros((0, 4, qsched.width), np.uint8)
-            ),
-            to_device(qsched.mbr_q[split:]),
-            to_device(qsched.parent_q),
-            to_device(qsched.confirm_mbr),
-            to_device(qsched.base.obj_level),
-            to_device(qsched.base.obj_slot),
-            to_device(qsched.base.obj_id),
-            to_device(qsched.origin),
-            to_device(qsched.inv_cell),
-            to_device(
-                qsched.inv_cell8
-                if qsched.inv_cell8 is not None
-                else qsched.inv_cell
-            ),
-            n_objects=qsched.n_objects,
-            cells=qsched.cells,
-            cells8=qsched.cells8,
-            split=split,
+            *staged.arrays,
+            **staged.statics,
             block_w=block_w,
-            root_unconditional=qsched.base.root_unconditional,
             interpret=interpret,
         )
 

@@ -30,9 +30,9 @@ launch (``engine.prepare`` / ``engine.wait`` / ``engine.fetch`` /
 
 * always: its ``time.perf_counter`` seconds are added to the named
   process counter (``prepare_s``, ...), as :func:`add` adds byte counts
-  (``h2d_bytes``, ``d2h_bytes``); the façade folds them into the
-  tenant's ``AccessStats`` (:func:`drain_counters`), so they are
-  operator metrics that ``SpatialIndex.metrics()`` exports;
+  (``h2d_bytes``, ``d2h_bytes``) and ``schedule_stagings``; the façade
+  folds them into the tenant's ``AccessStats`` (:func:`drain_counters`),
+  so they are operator metrics that ``SpatialIndex.metrics()`` exports;
 * while the tracer is enabled: an "X" event in the ring buffer, as
   ``span()`` records;
 * while a JAX profiler session collects: a ``jax.profiler.TraceAnnotation``

@@ -278,13 +278,10 @@ class TestLaunchStages:
                 front.submit("t", "region", q) for q in qs])
         n, levels = rt.index.n_objects, rt.index.schedule.levels
         if kind == "pallas":
-            sched = rt.index.schedule
-            win_off, _ = ops.parent_windows(sched.parent, sched.n_real,
-                                            block_w=128)
-            staged = [queries.astype(np.float32), sched.mbr_cm,
-                      sched.parent, sched.obj_mbr, sched.obj_level,
-                      sched.obj_slot, sched.obj_id, win_off]
+            # the schedule and its parent windows stay resident from the
+            # warm launch on
             rows = queries.shape[0]
+            staged = [queries.astype(np.float32)]
         else:
             # the server's padded query block; its schedule stays resident
             rows = rt.index._backend.server.query_block
