@@ -632,6 +632,7 @@ def bench_stream_scan():
             n_objects=sched_big.n_objects,
             root_unconditional=sched_big.root_unconditional,
             test_object_mbr=sched_big.test_object_mbr,
+            n_shared=sched_big.n_shared,
             stream=True,
         ),
         iters=1, warm=False,

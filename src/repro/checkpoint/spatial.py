@@ -76,6 +76,7 @@ def index_state(idx) -> Tuple[dict, dict]:
             "n_objects": int(sched.n_objects),
             "root_unconditional": bool(sched.root_unconditional),
             "test_object_mbr": bool(sched.test_object_mbr),
+            "n_shared": int(sched.n_shared),
         },
         "has_quantized": art._quantized is not None,
         "has_updates": idx._updates is not None,
@@ -182,6 +183,7 @@ def restore_index(meta: dict, arrays: dict, *, backend: str,
         n_objects=int(s["n_objects"]),
         root_unconditional=bool(s["root_unconditional"]),
         test_object_mbr=bool(s["test_object_mbr"]),
+        n_shared=int(s.get("n_shared", 0)),
     )
     quantized = None
     if meta.get("has_quantized"):

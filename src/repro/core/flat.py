@@ -229,8 +229,15 @@ class LevelSchedule:
               its MBR — True for tree schedules; the group pyramid instead
               requires overlap at every level (False).
     test_object_mbr: whether an object hit additionally requires the entry
-              MBR to overlap the query (True for trees; the pyramid's
-              deepest group *is* the membership test, False).
+              MBR to overlap the query (True for trees; False for the
+              pyramid, whose deepest group is the membership test of
+              every object alone in it).
+    n_shared: pyramid schedules only: entries ``[0, n_shared)`` share
+              their deepest group with another entry (equal centroids
+              land in the EQ quadrant at every level, so no depth parts
+              them).  Those entries alone are confirmed against their own
+              MBR (:func:`confirm_shared`); 0 where the build isolates
+              every object, and the search then has no object test.
     """
 
     mbr_cm: np.ndarray
@@ -243,6 +250,7 @@ class LevelSchedule:
     n_objects: int
     root_unconditional: bool = True
     test_object_mbr: bool = True
+    n_shared: int = 0
 
     @property
     def levels(self) -> int:
@@ -262,10 +270,9 @@ class QuantizedSchedule:
     contains its exact box and the quantized level sweep prunes a
     *superset* of the exact survivors — it can never drop a true hit.
     Survivors get one exact float32 confirming pass against
-    ``confirm_mbr`` (the entry's own MBR for tree schedules; the entry's
-    deepest group MBR for pyramid schedules — in both cases an exact
-    overlap there implies every enclosing ancestor overlaps, so confirmed
-    hit sets are bit-identical to the float32 path).  Streaming uint16
+    ``confirm_mbr``, each entry's own MBR: an exact overlap there implies
+    every enclosing ancestor overlaps, so confirmed hit sets are
+    bit-identical to the float32 path.  Streaming uint16
     node tiles + uint16 parent slots moves half the bytes per query of
     the float32 schedule (DESIGN.md §7).
 
@@ -416,14 +423,86 @@ def ancestor_chains(schedule: LevelSchedule, k_levels: int) -> np.ndarray:
     return chains[:, :k_levels].astype(np.int32)
 
 
+def pyramid_entries(obj_mbrs, deepest_group, levels: int) -> dict:
+    """The object entries of a pyramid schedule, one per object.
+
+    Objects that share their deepest group with another object come
+    first, in id order, then the objects alone in theirs; ``n_shared``
+    counts the first.  Where every object is alone the entries are in id
+    order and ``n_shared`` is 0.  Returns the ``obj_*`` fields and
+    ``n_shared`` of :class:`LevelSchedule`.
+    """
+    obj_mbr = np.asarray(obj_mbrs, np.float32).reshape(-1, 4)
+    slot = np.asarray(deepest_group).astype(np.int32)
+    n = slot.shape[0]
+    shared = np.bincount(slot)[slot] > 1
+    n_shared = int(shared.sum())
+    order = np.arange(n, dtype=np.int32)
+    if n_shared:
+        order = np.concatenate(
+            [np.flatnonzero(shared), np.flatnonzero(~shared)]
+        ).astype(np.int32)
+        obj_mbr, slot = obj_mbr[order], slot[order]
+    return dict(obj_mbr=obj_mbr, obj_level=np.full((n,), levels - 1, np.int32),
+                obj_slot=slot, obj_id=order, n_shared=n_shared)
+
+
+def confirm_shared(hit, queries, shared_mbr, xp=jnp, n_shared=None):
+    """The object test of a pyramid's shared entries (DESIGN.md §3.1).
+
+    ``hit`` is the (Q, E) entry-hit mask of the level sweep, whose first
+    ``U = shared_mbr.shape[0]`` columns are the entries that share their
+    deepest group; those alone are kept only where their own MBR meets
+    the query (closed bounds).  Returns ``(hit, confirm)``: the confirmed
+    mask and a (Q, 2) int32 of each query's candidates before the test
+    and hits after it.  ``xp`` is ``np`` or ``jnp``.
+
+    ``n_shared`` (a scalar, possibly traced) counts the shared entries
+    where ``U`` is a rounded-up width (:func:`confirm_width`): the
+    columns past it, objects alone in their group, are tested too, which
+    changes no answer, and are left out of the sums.
+    """
+    u = shared_mbr.shape[0]
+    cand = hit[:, :u]
+    ok = cand & _overlaps(shared_mbr[None, :, :], queries[:, None, :])
+    if n_shared is None:
+        counted = cand, ok
+    else:
+        real = xp.arange(u)[None, :] < n_shared
+        counted = cand & real, ok & real
+    confirm = xp.stack([c.sum(axis=1) for c in counted], axis=1)
+    return (xp.concatenate([ok, hit[:, u:]], axis=1),
+            confirm.astype(xp.int32))
+
+
+def confirm_width(n_shared: int, n_entries: int) -> int:
+    """The static width of a pyramid's confirmed prefix: ``n_shared``
+    rounded up to a 32nd to 64th of itself, in whole 128-lane tiles, at
+    most ``n_entries``; 0 where nothing is shared.
+
+    Builds of one configuration over other data draws share far more
+    than they differ (4e6 bit boxes: 3,908,094 and 3,908,465 shared), so
+    with the exact count passed as an operand they lower to one program
+    and a compilation cache serves them all.  For an object alone in its
+    deepest group the object test equals the group test, so testing the
+    columns past ``n_shared`` changes no answer.
+    """
+    if not n_shared:
+        return 0
+    step = max(128, 1 << max(int(n_shared).bit_length() - 6, 0))
+    return min(int(n_entries), -(-int(n_shared) // step) * step)
+
+
 def pyramid_schedule(pyr, obj_mbrs: np.ndarray) -> LevelSchedule:
     """Lower a :class:`repro.core.bulk.GroupPyramid` to the level schedule.
 
     Dense group ids are the slots; ``bulk._group_bounds`` already pads
     unused ids with +inf/-inf sentinels.  Group nesting (a level-``l``
     group's members share one level-``l-1`` group) makes the parent map
-    well defined.  Search semantics match :func:`repro.core.bulk.
-    pyramid_search`: an object survives iff every ancestor group overlaps.
+    well defined.  An object is a candidate iff every ancestor group
+    overlaps (:func:`repro.core.bulk.pyramid_search`); objects that share
+    their deepest group are confirmed against their own MBR, so hits are
+    the exact overlap on any data (:func:`pyramid_entries`).
     """
     group_of = np.asarray(pyr.group_of)       # (L, n)
     group_mbr = np.asarray(pyr.group_mbr, np.float32)  # (L, n, 4)
@@ -436,11 +515,8 @@ def pyramid_schedule(pyr, obj_mbrs: np.ndarray) -> LevelSchedule:
         mbr_cm=np.ascontiguousarray(group_mbr.transpose(0, 2, 1)),
         parent=parent,
         n_real=n_real,
-        obj_mbr=np.asarray(obj_mbrs, np.float32),
-        obj_level=np.full((n,), levels - 1, np.int32),
-        obj_slot=group_of[levels - 1].astype(np.int32),
-        obj_id=np.arange(n, dtype=np.int32),
         n_objects=n,
         root_unconditional=False,
         test_object_mbr=False,
+        **pyramid_entries(obj_mbrs, group_of[levels - 1], levels),
     )

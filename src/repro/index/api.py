@@ -231,6 +231,11 @@ class AccessStats:
     h2d_bytes: int = 0            # host arrays staged to the device
     schedule_stagings: int = 0    # schedules copied whole to the device
     d2h_bytes: int = 0            # outputs copied back
+    padded_queries: int = 0       # padding rows launched with short batches
+    # the object test of a pyramid's shared entries (DESIGN.md §3.1):
+    # their candidates before it and hits after it, over every query
+    confirm_candidates: int = 0
+    confirm_hits: int = 0
 
     def record(self, n_queries: int, accesses: int, launches: int) -> None:
         self.queries += int(n_queries)
@@ -456,6 +461,14 @@ class BuildArtifacts:
 
                 self._schedule = ops.hilbert_permute(self._schedule)
         return self._schedule
+
+    @property
+    def unisolated_objects(self) -> int:
+        """Objects that share their deepest pyramid group with another
+        object, which the search confirms against their own MBR
+        (``LevelSchedule.n_shared``); 0 for the trees, which test every
+        object."""
+        return self.schedule.n_shared
 
     @property
     def quantized(self):
@@ -864,6 +877,7 @@ class SpatialIndex:
         reg = _obs_metrics.MetricsRegistry()
         labels = {"tenant": tenant} if tenant else None
         _obs_metrics.stats_into(reg, self.stats, labels=labels)
+        _obs_metrics.build_into(reg, self.artifacts, labels=labels)
         return reg
 
     # -- durability (DESIGN.md §9) -------------------------------------

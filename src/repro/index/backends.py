@@ -17,13 +17,15 @@ the façade's :class:`AccessStats` ledger means the same thing everywhere.
 
 from __future__ import annotations
 
+import dataclasses
+
 import numpy as np
 
 import jax
 import jax.numpy as jnp
 
 from repro.core import mbr as M
-from repro.core.flat import LevelSchedule
+from repro.core.flat import LevelSchedule, confirm_shared
 from repro.kernels import ops
 from repro.obs import counters as _obs_counters
 from repro.obs import trace as _obs_trace
@@ -103,7 +105,8 @@ def schedule_region_numpy(schedule: LevelSchedule, queries: np.ndarray):
 
     Same recurrence as the fused kernel: ``active[l] = active[l-1][parent]
     & overlaps`` (level 0 unconditional at the root slot for tree
-    schedules).  Returns ``(hits, visits (Q, L))``.
+    schedules), and the same object test of a pyramid's shared entries.
+    Returns ``(hits, visits (Q, L))``.
     """
     queries = np.asarray(queries, np.float32)
     nq = queries.shape[0]
@@ -127,6 +130,10 @@ def schedule_region_numpy(schedule: LevelSchedule, queries: np.ndarray):
         entry_act = entry_act & _overlap_np(
             schedule.obj_mbr[None, :, :], queries[:, None, :]
         )
+    elif schedule.n_shared:
+        entry_act, confirm = confirm_shared(
+            entry_act, queries, schedule.obj_mbr[:schedule.n_shared], xp=np)
+        ops.count_confirm(confirm)
     hits = np.zeros((nq, max(schedule.n_objects, 1)), bool)
     np.maximum.at(hits, (slice(None), schedule.obj_id), entry_act)
     return hits, visits
@@ -151,7 +158,10 @@ class LaxBackend:
     def region(self, queries: np.ndarray):
         with _obs_trace.span("backend.lax", queries=queries.shape[0]):
             out = self._run(ops.to_device(queries, jnp.float32))
-            hits, visits = ops.fetch(*out)
+            hits, visits, *confirm = ops.fetch(
+                *(a for a in out if a is not None))
+            if confirm:
+                ops.count_confirm(confirm[0])
             return hits, visits, 1
 
 
@@ -165,6 +175,7 @@ def _make_lax_sweep(schedule: LevelSchedule):
     levels, width, _ = mbr_rm.shape
     root_unconditional = schedule.root_unconditional
     test_object_mbr = schedule.test_object_mbr
+    shared_mbr = obj_mbr[:schedule.n_shared] if schedule.n_shared else None
     n_obj = schedule.n_objects
 
     @jax.jit
@@ -188,11 +199,14 @@ def _make_lax_sweep(schedule: LevelSchedule):
         )  # acts: (L, Q, W)
         visits = jnp.transpose(acts.sum(axis=2, dtype=jnp.int32))
         hit = jnp.transpose(acts[obj_level, :, obj_slot])  # (Q, E)
+        confirm = None
         if test_object_mbr:
             hit = hit & _overlap_np(obj_mbr[None, :, :], queries[:, None, :])
+        elif shared_mbr is not None:
+            hit, confirm = confirm_shared(hit, queries, shared_mbr)
         hits = jnp.zeros((nq, max(n_obj, 1)), jnp.bool_)
         hits = hits.at[:, obj_id].max(hit)
-        return hits, visits
+        return hits, visits, confirm
 
     return run
 
@@ -226,9 +240,15 @@ class PallasBackend:
     autotuner: ``autotune="auto"`` times the candidate grid of
     :mod:`repro.kernels.autotune` on the first query batch once the slot
     grid is wide enough to matter, ``"on"`` always does, ``"off"`` (or
-    any explicit ``block_w``/``query_block``) pins the fixed
-    configuration.  Winners are cached in ``BuildArtifacts.tuned`` keyed
-    by shape, so ``with_backend`` twins reuse the measurement.
+    an explicit ``block_w``) pins the fixed configuration.  Winners are
+    cached in ``BuildArtifacts.tuned`` keyed by shape, so
+    ``with_backend`` twins reuse the measurement.
+
+    ``query_block`` fixes every launch at that many queries: a larger
+    batch is split into blocks, and a shorter one is padded up with
+    ``flat.NEVER_MBR`` rows (counted in ``padded_queries``) that meet no
+    object and are sliced off before anything is counted, so one program
+    serves every batch size (DESIGN.md §12).
 
     The schedule is staged to the device on the first launch (the
     autotune probe or a warm-up) and stays there, with its parent-window
@@ -287,11 +307,7 @@ class PallasBackend:
             128 if self.block_w is None else self.block_w,
             self.query_block, True,
         )
-        if (
-            self.autotune == "off"
-            or self.block_w is not None
-            or self.query_block is not None
-        ):
+        if self.autotune == "off" or self.block_w is not None:
             return fixed
         width = self.schedule.width
         if self.autotune == "auto" and width < AUTO_MIN_WIDTH:
@@ -306,6 +322,10 @@ class PallasBackend:
             cands = candidates(
                 width, nq, precision=self.precision, stream=self.stream
             )
+            if self.query_block is not None:  # the launch size is fixed
+                cands = list(dict.fromkeys(
+                    dataclasses.replace(c, query_block=self.query_block)
+                    for c in cands))
             cfg, _, refused = tune(
                 lambda c: lambda: np.asarray(self._run(probe, c)[0]), cands
             )
@@ -329,23 +349,18 @@ class PallasBackend:
                 self.schedule if self.qschedule is None else self.qschedule,
                 self.precision,
             )
-        if self.precision == "compact":
-            hits, visits = ops.pyramid_scan_compact(
-                self._staged, queries, block_w=cfg.block_w,
-                interpret=self.interpret, stream=self.stream,
-            )
-        elif self.precision == "compact8":
-            hits, visits = ops.pyramid_scan_compact8(
-                self._staged, queries, block_w=cfg.block_w,
-                interpret=self.interpret,
-            )
-        else:
-            hits, visits = ops.pyramid_scan(
-                self._staged, queries, block_w=cfg.block_w,
-                interpret=self.interpret, stream=self.stream,
-            )
-        hits, visits = ops.fetch(hits, visits)
-        return hits, visits, 1
+        out = ops.scan_staged(
+            self._staged, queries, block_w=cfg.block_w,
+            interpret=self.interpret, stream=self.stream,
+            pad_to=cfg.query_block,
+        )
+        hits, visits, *confirm = ops.fetch(*(a for a in out if a is not None))
+        n = queries.shape[0]
+        if confirm:
+            ops.count_confirm(confirm[0][:n])
+        if hits.shape[0] > n:
+            _obs_trace.add("padded_queries", hits.shape[0] - n)
+        return hits[:n], visits[:n], 1
 
     def _run(self, queries: np.ndarray, cfg):
         qb = cfg.query_block

@@ -47,7 +47,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import bulk
-from repro.core.flat import LevelSchedule
+from repro.core.flat import LevelSchedule, pyramid_entries
 
 from .pyramid_scan import COMPILER_PARAMS
 
@@ -378,6 +378,10 @@ def device_schedule(
     object the host ``flat.pyramid_schedule`` path produces, so every
     backend (host/lax/pallas/serve) serves it unchanged.
 
+    Objects the last level leaves sharing a group come first among the
+    entries and are confirmed against their own MBR at search time
+    (``LevelSchedule.n_shared``), so answers are exact on any data.
+
     ``order="hilbert"`` additionally renumbers every level's slots along
     the Hilbert curve of the slot-MBR centers (:func:`hilbert_permute`) —
     hit sets, ids, and per-level access counts are invariant under the
@@ -407,18 +411,14 @@ def device_schedule(
         )
     else:
         raise ValueError(f"unknown build engine {engine!r}")
-    group_of = np.asarray(group_of)
     schedule = LevelSchedule(
         mbr_cm=np.ascontiguousarray(np.asarray(mbr_cm)),
         parent=np.asarray(parent),
         n_real=np.asarray(n_real, np.int32),
-        obj_mbr=mbrs_f32,
-        obj_level=np.full((n,), levels - 1, np.int32),
-        obj_slot=group_of[levels - 1].astype(np.int32),
-        obj_id=np.arange(n, dtype=np.int32),
         n_objects=n,
         root_unconditional=False,
         test_object_mbr=False,
+        **pyramid_entries(mbrs_f32, np.asarray(group_of[levels - 1]), levels),
     )
     if order not in (None, "none", "hilbert"):
         raise ValueError(f"unknown slot order {order!r}")

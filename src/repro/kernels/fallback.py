@@ -32,6 +32,7 @@ import numpy as np
 
 import jax.numpy as jnp
 
+from repro.core.flat import confirm_shared
 from repro.obs import counters as _obs_counters
 
 
@@ -182,10 +183,15 @@ def _sweep_hier(xp, qq8, qq16, mbr8, mbr16, parent, *, root_unconditional):
 
 
 def _finish(xp, queries, hit, visits, gate_mbr, obj_id, n_objects,
-            alive=None):
-    """Shared epilogue: exact confirm gate, global-id scatter, tombstones."""
+            alive=None, shared_mbr=None):
+    """Shared epilogue: exact confirm gate (or the object test of a
+    pyramid's shared entries), global-id scatter, tombstones.  Returns
+    ``(hits, visits, confirm)`` as the kernel's ``_hits_epilogue``."""
+    confirm = None
     if gate_mbr is not None:
         hit = hit & _overlap(gate_mbr[None, :, :], queries[:, None, :])
+    elif shared_mbr is not None:
+        hit, confirm = confirm_shared(hit, queries, shared_mbr, xp=xp)
     nq = queries.shape[0]
     if xp is np:
         hits = np.zeros((nq, max(n_objects, 1)), bool)
@@ -197,12 +203,12 @@ def _finish(xp, queries, hit, visits, gate_mbr, obj_id, n_objects,
         visits = visits.astype(jnp.int32)
     if alive is not None:
         hits = hits & alive[None, :]
-    return hits, visits
+    return hits, visits, confirm
 
 
 def _twin_search(xp, queries, qeff, mbr_cm, parent, obj_level, obj_slot,
                  obj_id, *, n_objects, root_unconditional, uncond_from,
-                 gate_mbr, alive=None, stream=False):
+                 gate_mbr, alive=None, stream=False, shared_mbr=None):
     """One generic region-search rung; every public twin is a thin shell.
 
     ``qeff`` is what the sweep tests (float32 queries, or their outward
@@ -228,7 +234,8 @@ def _twin_search(xp, queries, qeff, mbr_cm, parent, obj_level, obj_slot,
         visits = xp.transpose(act.sum(axis=2).astype(xp.int32))
         hit = xp.transpose(act[obj_level, :, obj_slot])
     return _finish(
-        xp, queries, hit, visits, gate_mbr, obj_id, n_objects, alive=alive
+        xp, queries, hit, visits, gate_mbr, obj_id, n_objects, alive=alive,
+        shared_mbr=shared_mbr,
     )
 
 
@@ -240,14 +247,14 @@ def _twin_search(xp, queries, qeff, mbr_cm, parent, obj_level, obj_slot,
 def fused_search_lax(
     queries, mbr_cm, parent, obj_mbr, obj_level, obj_slot, obj_id,
     *, n_objects, block_w=128, root_unconditional=True,
-    test_object_mbr=True, interpret=None, stream=False,
+    test_object_mbr=True, n_shared=0, interpret=None, stream=False,
 ):
     del block_w, interpret  # kernel-only tuning knobs
     return _twin_search(
         jnp, queries, queries, mbr_cm, parent, obj_level, obj_slot, obj_id,
         n_objects=n_objects, root_unconditional=root_unconditional,
         uncond_from=None, gate_mbr=obj_mbr if test_object_mbr else None,
-        stream=stream,
+        stream=stream, shared_mbr=obj_mbr[:n_shared] if n_shared else None,
     )
 
 
@@ -263,7 +270,7 @@ def fused_search_live_lax(
         uncond_from=base_levels,
         gate_mbr=obj_mbr if test_object_mbr else None,
         alive=alive, stream=stream,
-    )
+    )[:2]
 
 
 def fused_search_compact_lax(
@@ -279,7 +286,7 @@ def fused_search_compact_lax(
         obj_level, obj_slot, obj_id,
         n_objects=n_objects, root_unconditional=root_unconditional,
         uncond_from=None, gate_mbr=confirm_mbr, stream=stream,
-    )
+    )[:2]
 
 
 def fused_search_compact_live_lax(
@@ -296,7 +303,7 @@ def fused_search_compact_live_lax(
         n_objects=n_objects, root_unconditional=root_unconditional,
         uncond_from=base_levels, gate_mbr=confirm_mbr, alive=alive,
         stream=stream,
-    )
+    )[:2]
 
 
 def fused_search_compact8_lax(
@@ -322,7 +329,9 @@ def fused_search_compact8_lax(
         )
     visits = jnp.transpose(act.sum(axis=2).astype(jnp.int32))
     hit = jnp.transpose(act[obj_level, :, obj_slot])
-    return _finish(jnp, queries, hit, visits, confirm_mbr, obj_id, n_objects)
+    return _finish(
+        jnp, queries, hit, visits, confirm_mbr, obj_id, n_objects
+    )[:2]
 
 
 # ---------------------------------------------------------------------------
@@ -333,17 +342,17 @@ def fused_search_compact8_lax(
 def fused_search_np(
     queries, mbr_cm, parent, obj_mbr, obj_level, obj_slot, obj_id,
     *, n_objects, block_w=128, root_unconditional=True,
-    test_object_mbr=True, interpret=None, stream=False,
+    test_object_mbr=True, n_shared=0, interpret=None, stream=False,
 ):
     del block_w, interpret
     queries = np.asarray(queries, np.float32)
+    obj_mbr = np.asarray(obj_mbr)
     return _twin_search(
         np, queries, queries, np.asarray(mbr_cm), np.asarray(parent),
         np.asarray(obj_level), np.asarray(obj_slot), np.asarray(obj_id),
         n_objects=n_objects, root_unconditional=root_unconditional,
-        uncond_from=None,
-        gate_mbr=np.asarray(obj_mbr) if test_object_mbr else None,
-        stream=stream,
+        uncond_from=None, gate_mbr=obj_mbr if test_object_mbr else None,
+        stream=stream, shared_mbr=obj_mbr[:n_shared] if n_shared else None,
     )
 
 
@@ -361,7 +370,7 @@ def fused_search_live_np(
         uncond_from=base_levels,
         gate_mbr=np.asarray(obj_mbr) if test_object_mbr else None,
         alive=np.asarray(alive, bool), stream=stream,
-    )
+    )[:2]
 
 
 def fused_search_compact_np(
@@ -381,7 +390,7 @@ def fused_search_compact_np(
         np.asarray(obj_level), np.asarray(obj_slot), np.asarray(obj_id),
         n_objects=n_objects, root_unconditional=root_unconditional,
         uncond_from=None, gate_mbr=np.asarray(confirm_mbr), stream=stream,
-    )
+    )[:2]
 
 
 def fused_search_compact_live_np(
@@ -402,7 +411,7 @@ def fused_search_compact_live_np(
         n_objects=n_objects, root_unconditional=root_unconditional,
         uncond_from=base_levels, gate_mbr=np.asarray(confirm_mbr),
         alive=np.asarray(alive, bool), stream=stream,
-    )
+    )[:2]
 
 
 def fused_search_compact8_np(
@@ -436,7 +445,7 @@ def fused_search_compact8_np(
     return _finish(
         np, queries, hit, visits, np.asarray(confirm_mbr),
         np.asarray(obj_id), n_objects,
-    )
+    )[:2]
 
 
 # ---------------------------------------------------------------------------

@@ -10,6 +10,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.core.flat import confirm_width
+
 from . import ref
 from .build import build_levels_jnp as build_levels_jnp  # noqa: F401
 from .build import build_levels_pallas as build_levels_pallas  # noqa: F401
@@ -35,12 +37,14 @@ from .pyramid_scan import per_level_region_search as _per_level
 from .pyramid_scan import pyramid_scan as _pyramid_scan
 from .pyramid_scan import pyramid_scan_compact as _pyramid_scan_compact
 from .pyramid_scan import pyramid_scan_compact8 as _pyramid_scan_compact8
+from .pyramid_scan import scan_staged as _scan_staged
 from .pyramid_scan import stage_schedule as stage_schedule  # noqa: F401
 from .quantize import grid_params as grid_params  # noqa: F401 (re-export)
 from .quantize import quantize_cm_pallas as quantize_cm_pallas  # noqa: F401
 from .quantize import quantize_rows as quantize_rows  # noqa: F401 (re-export)
 from .quantize import quantize_schedule as _quantize_schedule
 from .rmsnorm import rmsnorm as _rmsnorm
+from .transfer import count_confirm as count_confirm  # noqa: F401
 from .transfer import fetch as fetch  # noqa: F401 (re-export)
 from .transfer import to_device as to_device  # noqa: F401 (re-export)
 
@@ -69,6 +73,7 @@ def fused_search(
     block_w: int = 128,
     root_unconditional: bool = True,
     test_object_mbr: bool = True,
+    n_shared: int = 0,
     interpret: bool | None = None,
     stream: bool = False,
     win_off=None,
@@ -79,7 +84,9 @@ def fused_search(
     Same computation as :func:`pyramid_scan` but over the unpacked
     ``LevelSchedule`` arrays, so callers (e.g. the spatial server) can
     ``vmap``/``pmap`` it over query blocks with the schedule arrays held
-    constant.  Returns ``(hits (Q, n_objects), visits (Q, L))``.
+    constant.  Returns ``(hits (Q, n_objects), visits (Q, L), confirm)``;
+    ``confirm`` is the (Q, 2) object-test sums of a pyramid's
+    ``n_shared`` leading entries (``LevelSchedule.n_shared``), else None.
 
     ``stream=True`` runs the HBM-streaming double-buffered sweep
     (DESIGN.md §12); pass the ``(win_off, win_w)`` parent windows from
@@ -87,12 +94,15 @@ def fused_search(
     """
     if interpret is None:
         interpret = interpret_default()
+    confirm_w = confirm_width(n_shared, obj_id.shape[0])
     return _fused_search(
         queries, mbr_cm, parent, obj_mbr, obj_level, obj_slot, obj_id,
+        jnp.int32(n_shared) if confirm_w else None,
         n_objects=n_objects,
         block_w=block_w,
         root_unconditional=root_unconditional,
         test_object_mbr=test_object_mbr,
+        confirm_w=confirm_w,
         interpret=interpret,
         stream=stream,
         win_off=win_off,
@@ -378,6 +388,20 @@ def pyramid_scan(schedule, queries, *, block_w: int = 128,
     return _pyramid_scan(
         schedule, queries, block_w=block_w, interpret=interpret, stream=stream
     )
+
+
+def scan_staged(staged, queries, *, block_w: int = 128,
+                interpret: bool | None = None, stream: bool = False,
+                pad_to: int | None = None):
+    """One fused launch over a :func:`stage_schedule` form of any
+    precision; returns device ``(hits, visits, confirm)``, ``confirm``
+    the (Q, 2) object-test sums of a pyramid's shared entries or None.
+    ``pad_to`` pads a shorter batch with :data:`repro.core.flat.NEVER_MBR` rows, kept in
+    the outputs.  ``interpret=None`` follows :func:`interpret_default`."""
+    if interpret is None:
+        interpret = interpret_default()
+    return _scan_staged(staged, queries, block_w=block_w,
+                        interpret=interpret, stream=stream, pad_to=pad_to)
 
 
 def per_level_region_search(schedule, queries, *, block_w: int = 128):

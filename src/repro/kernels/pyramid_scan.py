@@ -55,11 +55,13 @@ from repro.core.flat import (
     LevelSchedule,
     QuantizedSchedule,
     _overlaps,
+    confirm_shared,
+    confirm_width,
 )
 from repro.obs import counters as _obs_counters
 from repro.obs import trace as _obs_trace
 
-from .transfer import to_device
+from .transfer import count_confirm, to_device
 
 # Scoped-VMEM budget of the sweep, pair-sweep and build kernels.  The
 # compiler's default (16 MiB on v5e) is far below the chip's 128 MiB; the
@@ -691,47 +693,63 @@ def _quantize_queries(queries, origin, inv_cell, cells: int):
 
 
 def _hits_epilogue(act, queries, gate_mbr, obj_level, obj_slot, obj_id,
-                   n_objects: int, alive=None):
-    """Shared jnp epilogue: (L, Q, W) active mask -> (hits, visits).
+                   n_objects: int, alive=None, shared_mbr=None,
+                   n_shared=None):
+    """Shared jnp epilogue: (L, Q, W) active mask -> (hits, visits,
+    confirm).
 
     Per-level access counts: padded slots carry sentinel MBRs and are
     never active, so a plain sum counts exactly the visited real nodes.
     Entry e hits iff its holding node is active and (when ``gate_mbr`` is
     given) its exact float32 MBR overlaps the query — the confirming pass
     of the quantized paths and the object-MBR test of tree schedules are
-    the same operation."""
+    the same operation.  ``shared_mbr`` instead tests a pyramid's leading
+    entries, the ``n_shared`` that share their deepest group up to a
+    rounded width (:func:`repro.core.flat.confirm_shared`); ``confirm`` is
+    then its (Q, 2) candidates/hits sums, else None."""
     visits = jnp.transpose(act.sum(axis=2, dtype=jnp.int32))  # (Q, L)
     hit = jnp.transpose(act[obj_level, :, obj_slot])           # (Q, E)
+    confirm = None
     if gate_mbr is not None:
         hit = hit & _overlaps(gate_mbr[None, :, :], queries[:, None, :])
+    elif shared_mbr is not None:
+        hit, confirm = confirm_shared(hit, queries, shared_mbr,
+                                      n_shared=n_shared)
     q = queries.shape[0]
     hits = jnp.zeros((q, max(n_objects, 1)), jnp.bool_)
     hits = hits.at[:, obj_id].max(hit)
     if alive is not None:
         # Tombstone mask: deleted ids drop out here, in the same jit program.
         hits = hits & alive[None, :]
-    return hits, visits
+    return hits, visits, confirm
 
 
 @functools.partial(
     jax.jit,
     static_argnames=(
         "n_objects", "block_w", "root_unconditional", "test_object_mbr",
-        "interpret", "stream", "win_w",
+        "confirm_w", "interpret", "stream", "win_w",
     ),
 )
 def _fused_search(
     queries, mbr_cm, parent, obj_mbr, obj_level, obj_slot, obj_id,
+    n_shared=None,
     *,
     n_objects: int,
     block_w: int,
     root_unconditional: bool,
     test_object_mbr: bool,
     interpret: bool,
+    confirm_w: int = 0,
     stream: bool = False,
     win_off=None,
     win_w: int | None = None,
 ):
+    """Sweep + epilogue of a :class:`LevelSchedule`; returns ``(hits,
+    visits, confirm)``.  ``confirm_w`` is the static width of a pyramid's
+    confirmed prefix (:func:`repro.core.flat.confirm_width`) and
+    ``n_shared`` the scalar count of its shared entries; ``confirm`` is
+    None where ``confirm_w`` is 0."""
     act = level_sweep(
         queries, mbr_cm, parent,
         block_w=block_w,
@@ -744,6 +762,8 @@ def _fused_search(
     return _hits_epilogue(
         act, queries, obj_mbr if test_object_mbr else None,
         obj_level, obj_slot, obj_id, n_objects,
+        shared_mbr=obj_mbr[:confirm_w] if confirm_w else None,
+        n_shared=n_shared,
     )
 
 
@@ -793,11 +813,16 @@ def stage_schedule(schedule, precision: str = "float32") -> StagedSchedule:
     each call adds 1 to ``schedule_stagings`` (DESIGN.md §13).
     """
     if precision == "float32":
+        confirm_w = confirm_width(schedule.n_shared,
+                                  schedule.obj_id.shape[0])
         arrays = (schedule.mbr_cm, schedule.parent, schedule.obj_mbr,
                   schedule.obj_level, schedule.obj_slot, schedule.obj_id)
+        if confirm_w:  # the count is an operand: one program per width
+            arrays += (np.int32(schedule.n_shared),)
         statics = dict(n_objects=schedule.n_objects,
                        root_unconditional=schedule.root_unconditional,
-                       test_object_mbr=schedule.test_object_mbr)
+                       test_object_mbr=schedule.test_object_mbr,
+                       confirm_w=confirm_w)
     elif precision in ("compact", "compact8"):
         base = schedule.base
         objs = (schedule.confirm_mbr, base.obj_level, base.obj_slot,
@@ -848,6 +873,65 @@ def _as_staged(schedule, precision: str) -> StagedSchedule:
     return schedule
 
 
+def _launch_report(staged, queries, *, block_w, stream, win_off, win_w):
+    if staged.precision == "compact8":
+        return _obs_counters.scan_report_compact8(
+            staged.source, queries, block_w=block_w)
+    report = (_obs_counters.scan_report_float32
+              if staged.precision == "float32"
+              else _obs_counters.scan_report_compact)
+    return report(staged.source, queries, block_w=block_w, stream=stream,
+                  win_off=win_off, win_w=win_w)
+
+
+def scan_staged(
+    staged: StagedSchedule,
+    queries,
+    *,
+    block_w: int = 128,
+    interpret: bool = False,
+    stream: bool = False,
+    pad_to: int | None = None,
+):
+    """One fused launch over a :class:`StagedSchedule` of any precision.
+
+    Returns device ``(hits, visits, confirm)``; ``confirm`` is the (Q, 2)
+    object-test sums of a pyramid's shared entries (float32 schedules
+    with ``n_shared``), else None.  ``pad_to`` pads a batch of fewer
+    queries up to that many with :data:`NEVER_MBR` rows, which meet no
+    object: inverted and infinitely far out at float32, and still
+    inverted once quantized outward (lo = the grid's last cell, hi = 0),
+    so at compact precision they meet only a node spanning the whole
+    grid.  Every batch size thus launches one program (the streamed
+    sweep compiles for whole query blocks only); the outputs keep the
+    padded rows, and the launch report sees the real queries alone.
+    """
+    precision = staged.precision
+    with _obs_trace.stage("engine.prepare", "prepare_s"):
+        win_off, win_w = staged.windows(block_w) if stream else (None, None)
+        if _obs_counters.collecting():  # side channel: eager wrappers only
+            _obs_counters.emit(_launch_report(
+                staged, queries, block_w=block_w, stream=stream,
+                win_off=win_off, win_w=win_w))
+        pad = 0 if pad_to is None else max(pad_to - queries.shape[0], 0)
+        if pad:
+            queries = np.concatenate([np.asarray(queries, np.float32),
+                                      np.broadcast_to(NEVER_MBR, (pad, 4))])
+        run = {"float32": _fused_search, "compact": _fused_search_compact,
+               "compact8": _fused_search_compact8}[precision]
+        kwargs = {} if precision == "compact8" else dict(
+            stream=stream, win_off=win_off, win_w=win_w)
+        out = run(
+            to_device(queries, jnp.float32),
+            *staged.arrays,
+            **staged.statics,
+            block_w=block_w,
+            interpret=interpret,
+            **kwargs,
+        )
+    return out if precision == "float32" else (*out, None)
+
+
 def pyramid_scan(
     schedule: LevelSchedule | StagedSchedule,
     queries,
@@ -860,10 +944,11 @@ def pyramid_scan(
 
     Returns ``(hits, visits)``: hits (Q, n_objects) bool object mask and
     visits (Q, L) int32 per-level access counts — both identical to the
-    host pointer search (tree schedules) / ``bulk.pyramid_search``
-    (pyramid schedules).  ONE kernel launch regardless of tree height.
-    ``stream=True`` uses the HBM-streaming kernel (DESIGN.md §12) —
-    bit-identical results, VMEM bounded by the tile/window working set.
+    host pointer search (tree schedules) / the pyramid's numpy sweep
+    (``index.backends.schedule_region_numpy``).  ONE kernel launch
+    regardless of tree height.  ``stream=True`` uses the HBM-streaming
+    kernel (DESIGN.md §12) — bit-identical results, VMEM bounded by the
+    tile/window working set.
 
     ``schedule`` is a host :class:`LevelSchedule`, staged to the device
     for this call alone, or its :func:`stage_schedule` form, which a
@@ -871,23 +956,10 @@ def pyramid_scan(
     stages only the queries, and plans the parent windows only on the
     first streamed launch at each ``block_w``.
     """
-    staged = _as_staged(schedule, "float32")
-    with _obs_trace.stage("engine.prepare", "prepare_s"):
-        win_off, win_w = staged.windows(block_w) if stream else (None, None)
-        if _obs_counters.collecting():  # side channel: eager wrappers only
-            _obs_counters.emit(_obs_counters.scan_report_float32(
-                staged.source, queries, block_w=block_w, stream=stream,
-                win_off=win_off, win_w=win_w))
-        return _fused_search(
-            to_device(queries, jnp.float32),
-            *staged.arrays,
-            **staged.statics,
-            block_w=block_w,
-            interpret=interpret,
-            stream=stream,
-            win_off=win_off,
-            win_w=win_w,
-        )
+    return scan_staged(
+        _as_staged(schedule, "float32"), queries, block_w=block_w,
+        interpret=interpret, stream=stream,
+    )[:2]
 
 
 @functools.partial(
@@ -934,7 +1006,7 @@ def _fused_search_compact(
     )  # (L, Q, W) candidate mask, superset of the exact active mask
     return _hits_epilogue(
         act, queries, confirm_mbr, obj_level, obj_slot, obj_id, n_objects
-    )
+    )[:2]
 
 
 def pyramid_scan_compact(
@@ -950,23 +1022,10 @@ def pyramid_scan_compact(
     ``visits`` reports the compact sweep's own (conservative) accesses.
     ``qsched`` may be its ``stage_schedule(qsched, "compact")`` form, as
     in :func:`pyramid_scan`."""
-    staged = _as_staged(qsched, "compact")
-    with _obs_trace.stage("engine.prepare", "prepare_s"):
-        win_off, win_w = staged.windows(block_w) if stream else (None, None)
-        if _obs_counters.collecting():  # side channel: eager wrappers only
-            _obs_counters.emit(_obs_counters.scan_report_compact(
-                staged.source, queries, block_w=block_w, stream=stream,
-                win_off=win_off, win_w=win_w))
-        return _fused_search_compact(
-            to_device(queries, jnp.float32),
-            *staged.arrays,
-            **staged.statics,
-            block_w=block_w,
-            interpret=interpret,
-            stream=stream,
-            win_off=win_off,
-            win_w=win_w,
-        )
+    return scan_staged(
+        _as_staged(qsched, "compact"), queries, block_w=block_w,
+        interpret=interpret, stream=stream,
+    )[:2]
 
 
 @functools.partial(
@@ -1016,7 +1075,7 @@ def _fused_search_compact8(
         )
     return _hits_epilogue(
         act, queries, confirm_mbr, obj_level, obj_slot, obj_id, n_objects
-    )
+    )[:2]
 
 
 def pyramid_scan_compact8(
@@ -1031,18 +1090,10 @@ def pyramid_scan_compact8(
     True)``, or its ``stage_schedule(qsched, "compact8")`` form.  Hit
     sets bit-identical to every other precision; upper-level tiles
     stream at 1 byte per coordinate (DESIGN.md §12)."""
-    staged = _as_staged(qsched, "compact8")
-    with _obs_trace.stage("engine.prepare", "prepare_s"):
-        if _obs_counters.collecting():  # side channel: eager wrappers only
-            _obs_counters.emit(_obs_counters.scan_report_compact8(
-                staged.source, queries, block_w=block_w))
-        return _fused_search_compact8(
-            to_device(queries, jnp.float32),
-            *staged.arrays,
-            **staged.statics,
-            block_w=block_w,
-            interpret=interpret,
-        )
+    return scan_staged(
+        _as_staged(qsched, "compact8"), queries, block_w=block_w,
+        interpret=interpret,
+    )[:2]
 
 
 @functools.partial(
@@ -1087,7 +1138,7 @@ def _fused_search_live(
     return _hits_epilogue(
         act, queries, obj_mbr if test_object_mbr else None,
         obj_level, obj_slot, obj_id, n_objects, alive=alive,
-    )
+    )[:2]
 
 
 @functools.partial(
@@ -1133,7 +1184,7 @@ def _fused_search_compact_live(
     return _hits_epilogue(
         act, queries, confirm_mbr, obj_level, obj_slot, obj_id, n_objects,
         alive=alive,
-    )
+    )[:2]
 
 
 def per_level_region_search(
@@ -1183,6 +1234,10 @@ def per_level_region_search(
         entry_act = entry_act & _overlaps(
             schedule.obj_mbr[None, :, :], q[:, None, :]
         )
+    elif schedule.n_shared:
+        entry_act, confirm = confirm_shared(
+            entry_act, q, schedule.obj_mbr[:schedule.n_shared], xp=np)
+        count_confirm(confirm)
     hits = np.zeros((nq, max(schedule.n_objects, 1)), bool)
     np.maximum.at(hits, (slice(None), schedule.obj_id), entry_act)
     return hits, visits, launches

@@ -188,14 +188,10 @@ def quantize_schedule(
     pdtype = (
         np.uint16 if schedule.width <= np.iinfo(np.uint16).max else np.int32
     )
-    if schedule.test_object_mbr:
-        confirm = np.asarray(schedule.obj_mbr, np.float32)
-    else:
-        # Pyramid schedules: the entry's deepest group MBR is the exact
-        # membership box (nested inside every ancestor, DESIGN.md §7).
-        confirm = np.ascontiguousarray(
-            schedule.mbr_cm[schedule.obj_level, :, schedule.obj_slot]
-        ).astype(np.float32)
+    # Every entry is confirmed against its own MBR, which every ancestor
+    # box holds (DESIGN.md §7): exact on trees and on pyramids whose
+    # deepest groups hold several objects alike.
+    confirm = np.asarray(schedule.obj_mbr, np.float32)
     mbr_q8 = None
     inv_cell8 = None
     if split is None:

@@ -3,7 +3,9 @@
 Every launch stages its host inputs with :func:`to_device` and brings
 its outputs back with :func:`fetch`, so the always-on stage counters see
 each copy: ``h2d_bytes`` and ``d2h_bytes``, and the ``engine.wait`` /
-``engine.fetch`` stage seconds.
+``engine.fetch`` stage seconds.  :func:`count_confirm` adds the fetched
+sums of a pyramid's object test to ``confirm_candidates`` and
+``confirm_hits``.
 """
 
 from __future__ import annotations
@@ -36,3 +38,12 @@ def fetch(*arrays):
         out = tuple(np.asarray(a) for a in arrays)
     _obs_trace.add("d2h_bytes", sum(a.nbytes for a in out))
     return out
+
+
+def count_confirm(confirm) -> None:
+    """Add a launch's fetched (Q, 2) object-test sums (candidates before
+    the test, hits after it; :func:`repro.core.flat.confirm_shared`) to
+    the ``confirm_candidates`` / ``confirm_hits`` counters."""
+    cand, hits = np.asarray(confirm, np.int64).reshape(-1, 2).sum(axis=0)
+    _obs_trace.add("confirm_candidates", int(cand))
+    _obs_trace.add("confirm_hits", int(hits))

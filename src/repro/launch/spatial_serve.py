@@ -246,6 +246,7 @@ class SpatialServer:
                 block_w=block_w,
                 root_unconditional=schedule.root_unconditional,
                 test_object_mbr=schedule.test_object_mbr,
+                n_shared=schedule.n_shared,
                 interpret=interpret,
             )
         inner = functools.partial(fn, **kwargs)
@@ -407,6 +408,8 @@ class SpatialServer:
                     [miss, np.broadcast_to(NEVER_MBR, (pad, 4))], axis=0
                 )
             blocks = miss.reshape(-1, qb, 4)
+        if pad:
+            _obs_trace.add("padded_queries", pad)
         hits, visits, launches = self._run_ladder(blocks)
         self.stats.batches_dispatched += 1
         self.stats.kernel_launches += launches
@@ -488,26 +491,32 @@ class SpatialServer:
                             jax.vmap(self._inner_lax,
                                      in_axes=self._batch_axes)
                         )
-                    hits, visits = self._vmapped_lax(
+                    out = self._vmapped_lax(
                         ops.to_device(blocks), *self._arrays
                     )
                 elif self._pmapped is not None and nb % n_dev == 0:
                     sharded = blocks.reshape(n_dev, nb // n_dev, qb, 4)
-                    hits, visits = self._pmapped(
+                    out = self._pmapped(
                         ops.to_device(sharded), *self._arrays
                     )
                 else:
-                    hits, visits = self._vmapped(
+                    out = self._vmapped(
                         ops.to_device(blocks), *self._arrays
                     )
-            hits, visits = ops.fetch(hits, visits)
+            # the float32 entries return a pyramid's object-test sums third
+            hits, visits, *confirm = ops.fetch(
+                *(a for a in out if a is not None))
+            if confirm:
+                ops.count_confirm(confirm[0])
             return hits.reshape(nb * qb, -1), visits.reshape(nb * qb, -1), nb
         # host: pure numpy, zero device launches
         if self._np_arrays is None:
             self._np_arrays = tuple(np.asarray(a) for a in self._arrays)
-        hits, visits = self._inner_np(
+        hits, visits, *confirm = self._inner_np(
             blocks.reshape(nb * qb, 4), *self._np_arrays
         )
+        if confirm and confirm[0] is not None:
+            ops.count_confirm(confirm[0])
         return np.asarray(hits), np.asarray(visits), 0
 
     def _put(self, key: bytes, value) -> None:

@@ -140,6 +140,15 @@ def stats_into(reg: MetricsRegistry, stats, *,
     return reg
 
 
+def build_into(reg: MetricsRegistry, artifacts, *, prefix: str = "index",
+               labels: Optional[Dict[str, Any]] = None) -> MetricsRegistry:
+    """Pour a build's gauges into ``reg``: ``unisolated_objects``, the
+    objects a pyramid leaves sharing their deepest group."""
+    reg.gauge(f"{prefix}_unisolated_objects", artifacts.unisolated_objects,
+              labels=labels, help="BuildArtifacts.unisolated_objects")
+    return reg
+
+
 def telemetry_into(reg: MetricsRegistry, tel, *,
                    labels: Optional[Dict[str, Any]] = None) -> MetricsRegistry:
     """Pour a ``ServeTelemetry`` snapshot into ``reg``: scalar counters,

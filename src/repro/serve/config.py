@@ -160,7 +160,8 @@ class TenantConfig:
         opts["backend"] = self.backend
         if self.backend in ("pallas", "serve"):
             opts.setdefault("precision", self.precision)
-        if self.backend == "serve":
+            # every launch holds one query block: the server pads to it,
+            # and so does the pallas adapter (one program per batch size)
             opts.setdefault(
                 "query_block",
                 self.query_block if self.query_block is not None

@@ -196,6 +196,13 @@ class ServingFrontEnd:
             slo_class=cls.name, deadline=arrival + cls.deadline_s,
             t_arrival=arrival,
         )
+        if req.deadline - now <= self.queue.est_service(group_key(req)):
+            # Stamped so long ago that even a launch now misses the
+            # deadline: the submitter is behind its own schedule.  Alone
+            # at once, such a request would cost a whole launch and keep
+            # the submitter behind; its launch deadline counts from
+            # admission instead, so it coalesces with the ones behind it.
+            req.deadline = now + cls.deadline_s
         # admission control: per-class queue-depth limit (DESIGN.md §11)
         if self.queue.pending(cls.name) >= cls.max_queue:
             if cls.overload == "shed":
@@ -358,6 +365,8 @@ class ServingFrontEnd:
         _obs_metrics.telemetry_into(reg, self.telemetry)
         for name, rt in sorted(self.tenants.items()):
             _obs_metrics.stats_into(reg, rt.stats,
+                                    labels={"tenant": name})
+            _obs_metrics.build_into(reg, rt.spatial.artifacts,
                                     labels={"tenant": name})
         return reg
 

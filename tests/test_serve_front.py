@@ -216,6 +216,55 @@ def test_overload_queue_parks_but_still_serves():
     assert front.telemetry.queued_overload == 1
 
 
+def test_open_loop_recovers_after_a_stall():
+    """An open-loop submitter that stamps each request with its scheduled
+    arrival (as the benchmark's does) and pumps after every submit falls
+    behind during a stall.  A launch here costs the same for one request
+    as for a block, as a padded one does, so launching each late request
+    on its own the moment it is submitted would keep the submitter behind
+    for good; the front end instead coalesces such requests, and once the
+    backlog is served launches are full blocks again and arrivals are on
+    time."""
+    clock = FakeClock(0.0)
+    front = _front(query_block=16, clock=clock, classes=[
+        {"name": "batch", "deadline_ms": 2000.0, "overload": "queue",
+         "max_queue": 65536},
+    ])
+    index = front.tenants["a"].index
+    region = index.region
+
+    def fixed_cost_region(rects):
+        out = region(rects)
+        clock.advance(0.52)
+        return out
+
+    index.region = fixed_cost_region
+    rate, seconds, stall_at = 24.0, 40.0, 5.0
+    targets = np.arange(1, int(rate * seconds)) / rate
+    tickets, lateness, stalled = [], [], False
+    for target in targets:
+        while clock() < target:
+            if not front.pump():
+                clock.advance(min(target - clock(), 1e-3))
+        if not stalled and target >= stall_at:
+            clock.advance(2.5)            # the host stalls once
+            stalled = True
+        lateness.append(clock() - target)
+        tickets.append(front.submit("a", "region", [0, 0, 9, 9], slo="batch",
+                                    t_arrival=target))
+        front.pump()
+    while any(not t.done for t in tickets):
+        if not front.pump():
+            clock.advance(1e-3)
+    assert max(lateness) > 2.0            # the stall made arrivals late
+    last = [t for t in tickets if t.t_arrival >= seconds - 10.0]
+    assert max(lateness[-len(last):]) < 0.6
+    sizes = {}
+    for t in last:
+        sizes[t.t_launch] = sizes.get(t.t_launch, 0) + 1
+    assert np.mean(list(sizes.values())) >= 12
+
+
 # ---------------------------------------------------------------------------
 # the hardened boundary
 # ---------------------------------------------------------------------------

@@ -164,8 +164,8 @@ def test_twin_stream_parity_float32(name):
         test_object_mbr=sched.test_object_mbr,
     )
     for fn in (fallback.fused_search_lax, fallback.fused_search_np):
-        h0, v0 = fn(qs, *args, **kwargs)
-        h1, v1 = fn(qs, *args, stream=True, **kwargs)
+        h0, v0, _ = fn(qs, *args, **kwargs)
+        h1, v1, _ = fn(qs, *args, stream=True, **kwargs)
         assert np.array_equal(np.asarray(h1), np.asarray(h0))
         assert np.array_equal(np.asarray(v1), np.asarray(v0))
 

@@ -13,13 +13,16 @@ else: only one process at a time may load the TPU compiler library, so
 describing it while a module is imported would make the test workers
 collect different tests.
 """
+import functools
 import os
 
+import numpy as np
 import pytest
 
 import jax
 import jax.numpy as jnp
 
+from repro.core.flat import NEVER_MBR
 from repro.kernels import ops
 
 Q = 16            # the serving query block
@@ -75,6 +78,29 @@ def _stream(s):
     )
 
 
+def _stream_padded(s):
+    """The pallas adapter's launch of a 3-request deadline batch at the
+    map configuration's size: padded to the query block (Mosaic refuses
+    the streamed sweep for Q = 3), with the object test of a pyramid
+    whose deepest groups hold most objects."""
+    n, levels, block_w = 4_000_000, 11, 512
+    padded = np.concatenate([np.zeros((3, 4), np.float32),
+                             np.broadcast_to(NEVER_MBR, (Q - 3, 4))])
+    run = functools.partial(
+        ops.fused_search, n_objects=n, block_w=block_w,
+        root_unconditional=False, test_object_mbr=False,
+        n_shared=3_900_000, interpret=False, stream=True, win_w=1024,
+    )
+    return jax.jit(run).lower(
+        _spec(s, padded.shape, jnp.float32),
+        _spec(s, (levels, 4, n), jnp.float32),
+        _spec(s, (levels, n), jnp.int32),
+        _spec(s, (n, 4), jnp.float32),
+        *(_spec(s, (n,), jnp.int32) for _ in range(3)),
+        win_off=_spec(s, (levels, -(-n // block_w)), jnp.int32),
+    )
+
+
 def _hier(s):
     return ops.level_sweep_hier.lower(
         _spec(s, (Q, 4), jnp.int32), _spec(s, (Q, 4), jnp.int32),
@@ -109,6 +135,7 @@ KERNELS = {
     "resident_f32": lambda s: _resident(s, jnp.float32, jnp.float32),
     "resident_u16": lambda s: _resident(s, jnp.uint16, jnp.int32),
     "stream_1e7": _stream,
+    "stream_padded_q3_4e6": _stream_padded,
     "hier_u8_u16": _hier,
     "pair_2048x2048": _pair,
     "quantize": _quantize,
