@@ -236,6 +236,11 @@ class AccessStats:
     # their candidates before it and hits after it, over every query
     confirm_candidates: int = 0
     confirm_hits: int = 0
+    # pyramid launches of the pallas adapter that return hit ids, and
+    # those of them answered with the dense mask because a capacity
+    # overflowed (DESIGN.md §12)
+    compact_launches: int = 0
+    compact_overflows: int = 0
 
     def record(self, n_queries: int, accesses: int, launches: int) -> None:
         self.queries += int(n_queries)

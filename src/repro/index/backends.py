@@ -349,18 +349,44 @@ class PallasBackend:
                 self.schedule if self.qschedule is None else self.qschedule,
                 self.precision,
             )
-        out = ops.scan_staged(
-            self._staged, queries, block_w=cfg.block_w,
-            interpret=self.interpret, stream=self.stream,
-            pad_to=cfg.query_block,
-        )
-        hits, visits, *confirm = ops.fetch(*(a for a in out if a is not None))
         n = queries.shape[0]
+        size = max(n, cfg.query_block or 0)
+        run = dict(block_w=cfg.block_w, interpret=self.interpret,
+                   stream=self.stream, pad_to=size)
+        if size > n:
+            _obs_trace.add("padded_queries", size - n)
+        if self._staged.members() is None:
+            out = ops.scan_staged(self._staged, queries, **run)
+            hits, visits, *confirm = ops.fetch(
+                *(a for a in out if a is not None))
+        else:
+            hits, visits, confirm = self._run_ids(queries, run)
         if confirm:
             ops.count_confirm(confirm[0][:n])
-        if hits.shape[0] > n:
-            _obs_trace.add("padded_queries", hits.shape[0] - n)
         return hits[:n], visits[:n], 1
+
+    def _run_ids(self, queries: np.ndarray, run: dict):
+        """One launch over a pyramid that returns hit ids where they fit
+        its capacities, and the dense rows rebuilt from them on the host
+        (DESIGN.md §12); where they do not, the same program's dense
+        mask.  Returns host ``(hits, visits, [confirm])``."""
+        visits, confirm, offsets, overflow, ids, hits = ops.scan_staged_ids(
+            self._staged, queries, **run)
+        _obs_trace.add("compact_launches", 1)
+        visits, offsets, overflow, ids, *confirm = ops.fetch(
+            visits, offsets, overflow, ids,
+            *(() if confirm is None else (confirm,)))
+        if overflow:
+            _obs_trace.add("compact_overflows", 1)
+            return ops.fetch(hits)[0], visits, confirm
+        n = queries.shape[0]
+        with _obs_trace.stage("engine.fetch", "fetch_s"):
+            ids = ids[:offsets[n]]
+            keep = ids >= 0
+            rows = np.repeat(np.arange(n), np.diff(offsets[:n + 1]))
+            hits = np.zeros((n, max(self.schedule.n_objects, 1)), bool)
+            hits[rows[keep], ids[keep]] = True
+        return hits, visits, confirm
 
     def _run(self, queries: np.ndarray, cfg):
         qb = cfg.query_block

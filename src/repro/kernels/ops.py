@@ -37,7 +37,10 @@ from .pyramid_scan import per_level_region_search as _per_level
 from .pyramid_scan import pyramid_scan as _pyramid_scan
 from .pyramid_scan import pyramid_scan_compact as _pyramid_scan_compact
 from .pyramid_scan import pyramid_scan_compact8 as _pyramid_scan_compact8
+from .pyramid_scan import _fused_search_ids as fused_search_ids  # noqa: F401
+from .pyramid_scan import ids_caps as ids_caps  # noqa: F401
 from .pyramid_scan import scan_staged as _scan_staged
+from .pyramid_scan import scan_staged_ids as _scan_staged_ids
 from .pyramid_scan import stage_schedule as stage_schedule  # noqa: F401
 from .quantize import grid_params as grid_params  # noqa: F401 (re-export)
 from .quantize import quantize_cm_pallas as quantize_cm_pallas  # noqa: F401
@@ -402,6 +405,20 @@ def scan_staged(staged, queries, *, block_w: int = 128,
         interpret = interpret_default()
     return _scan_staged(staged, queries, block_w=block_w,
                         interpret=interpret, stream=stream, pad_to=pad_to)
+
+
+def scan_staged_ids(staged, queries, *, block_w: int = 128,
+                    interpret: bool | None = None, stream: bool = False,
+                    pad_to: int | None = None, caps=None):
+    """One launch over a pyramid's :func:`stage_schedule` form that
+    returns its hits as ids where they fit ``caps`` (DESIGN.md §12):
+    device ``(visits, confirm, offsets, overflow, ids, hits)``.
+    ``interpret=None`` follows :func:`interpret_default`."""
+    if interpret is None:
+        interpret = interpret_default()
+    return _scan_staged_ids(staged, queries, block_w=block_w,
+                            interpret=interpret, stream=stream,
+                            pad_to=pad_to, caps=caps)
 
 
 def per_level_region_search(schedule, queries, *, block_w: int = 128):

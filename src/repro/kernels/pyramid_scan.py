@@ -73,6 +73,17 @@ COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES)
 # Tiles per SMEM block of the streamed sweep's window-offset table.
 STREAM_META_BLOCK = 1024
 
+# Ceilings of the static capacities of a pyramid launch that returns hit
+# ids (DESIGN.md §12, :func:`ids_caps`).  The deepest level's active mask
+# is cut into column blocks of one sweep tile (``block_w`` slots, every
+# query), at most IDS_BLOCKS of them non-empty; their active slots, at
+# most IDS_SLOTS, are expanded to their members in chunks of IDS_CHUNK
+# lanes, at most IDS_CHUNKS chunks: IDS_CHUNK × IDS_CHUNKS id lanes.
+IDS_BLOCKS = 256
+IDS_SLOTS = 1 << 13
+IDS_CHUNK = 16
+IDS_CHUNKS = 1 << 15
+
 
 # Survivor masks travel as int32 0/1 inside every sweep kernel: Mosaic
 # cannot select between boolean vectors, and the level recurrence below is
@@ -446,7 +457,7 @@ def parent_windows(
     jax.jit,
     static_argnames=(
         "block_w", "root_unconditional", "interpret", "onehot_gather",
-        "uncond_from", "stream", "win_w",
+        "uncond_from", "stream", "win_w", "padded",
     ),
 )
 def level_sweep(
@@ -462,8 +473,11 @@ def level_sweep(
     stream: bool = False,
     win_off: jnp.ndarray | None = None,   # (L, T) i32, see parent_windows
     win_w: int | None = None,
+    padded: bool = False,
 ) -> jnp.ndarray:
-    """Run the fused sweep; returns the (L, Q, W) per-level active mask.
+    """Run the fused sweep; returns the (L, Q, W) per-level active mask,
+    or with ``padded=True`` the kernel's own int8 0/1 mask, (L, Q, W)
+    padded to whole tiles with slots that are never active.
 
     ``uncond_from`` marks the first FLAT level: levels ``>= uncond_from``
     skip the parent gate and test every slot against the query directly —
@@ -527,7 +541,7 @@ def level_sweep(
             compiler_params=COMPILER_PARAMS,
             interpret=interpret,
         )(queries, mbr_cm, parent)
-        return act[:, :, :w] != 0
+        return act if padded else act[:, :, :w] != 0
     if win_off is None or win_w is None:
         raise ValueError(
             "stream=True needs (win_off, win_w) from parent_windows()"
@@ -576,7 +590,7 @@ def level_sweep(
         compiler_params=COMPILER_PARAMS,
         interpret=interpret,
     )(meta, queries, mbr_cm, parent)
-    return act[:, :, :w] != 0
+    return act if padded else act[:, :, :w] != 0
 
 
 def _stream_meta(win_off):
@@ -767,6 +781,222 @@ def _fused_search(
     )
 
 
+def ids_caps(staged: "StagedSchedule", n_launch: int, block_w: int
+             ) -> Tuple[int, int, int, int]:
+    """The static capacities ``(blocks, slots, chunk, chunks)`` of an id
+    launch of ``n_launch`` queries over ``staged`` with tiles of
+    ``block_w`` slots: each ceiling of the module cut to what such a
+    launch can hold, and the id lanes to no more than the entries of the
+    ``(n_launch, n_objects)`` mask they replace."""
+    width, n = staged.source.width, staged.statics["n_objects"]
+    return (min(IDS_BLOCKS, -(-width // block_w)),
+            min(IDS_SLOTS, n_launch * width),
+            IDS_CHUNK,
+            min(IDS_CHUNKS, -(-n_launch * n // IDS_CHUNK)))
+
+
+def _search(cum, target, lo, hi, steps: int):
+    """Per element of ``target``: the first ``j`` in ``[lo, hi)`` with
+    ``cum[j] > target``, else ``hi``, over a flat non-decreasing int32
+    ``cum``; a branchless binary search of ``steps`` halvings, one
+    gather each (``2**steps >= hi - lo``)."""
+    last = cum.shape[0] - 1
+
+    def halve(k, pos):
+        nxt = pos + jnp.left_shift(1, steps - 1 - k)
+        ok = (nxt <= hi) & (cum[jnp.minimum(nxt - 1, last)] <= target)
+        return jnp.where(ok, nxt, pos)
+
+    return jax.lax.fori_loop(0, steps, halve,
+                             jnp.broadcast_to(lo, target.shape))
+
+
+def _steps(n: int) -> int:
+    return max(int(n).bit_length(), 1)
+
+
+def _ids_fit(act, slot_start, *, block: int, caps):
+    """Whether the padded (Q, Wp) deepest active mask ``act`` fits every
+    capacity of :func:`_ids_epilogue`: its non-empty column blocks, its
+    active slots and their member chunks, each counted by one reduction
+    over ``act``."""
+    n_blk, n_slot, chunk, n_chunk = caps
+    q, wp = act.shape
+    size = slot_start[1:] - slot_start[:-1]
+    n_ch = jnp.pad((size + chunk - 1) // chunk, (0, wp - size.shape[0]))
+    on = act.astype(jnp.int32)
+    blocks = (on.reshape(q, wp // block, block).max(axis=(0, 2)) > 0).sum()
+    return ((blocks <= n_blk) & (on.sum() <= n_slot)
+            & ((on * n_ch[None, :]).sum() <= n_chunk))
+
+
+def _ids_epilogue(act, queries, members, *, block: int, confirm_w: int,
+                  caps):
+    """The deepest level's padded (Q, Wp) int8 active mask -> hit ids
+    (DESIGN.md §12), where :func:`_ids_fit` holds.
+
+    The non-empty column blocks of ``block`` slots are compacted and
+    gathered whole, then their active slots listed in query-major, slot
+    order, and each slot expanded to its members (``members``, the table
+    :func:`_member_table` stages: ``slot_start`` and the member-ordered
+    ids and four MBR coordinates) in chunks of ``chunk`` lanes.  Where
+    ``confirm_w`` says that some slot holds more than one entry, their
+    members get the object test and are counted in ``confirm`` (Q, 2),
+    the candidates and hits of :func:`confirm_shared`: in a pyramid the
+    entries of such slots are exactly its ``n_shared`` first, and a
+    slot's one entry meets a query where its slot does
+    (:func:`_member_table`).  No stage scatters or sorts: each compaction
+    is a prefix sum and a binary search into it.
+
+    Returns ``(confirm, offsets (Q + 1,), ids (chunks × chunk,))``: query
+    ``q``'s id lanes are ``ids[offsets[q]:offsets[q + 1]]``, -1 where a
+    lane holds no hit."""
+    slot_start, mem_id, *mem_mbr = members
+    n_blk, n_slot, chunk, n_chunk = caps
+    q, wp = act.shape
+    nb = wp // block
+    col = act.reshape(q, nb, block).astype(jnp.int32).sum(axis=(0, 2))
+    ne_cum = jnp.cumsum(col > 0, dtype=jnp.int32)
+    blk = _search(ne_cum, jnp.arange(n_blk, dtype=jnp.int32), 0, nb,
+                  _steps(nb))                             # k-th non-empty
+    blk_ok = blk < nb
+    blk = jnp.where(blk_ok, blk, 0)
+    cols = jax.vmap(lambda b: jax.lax.dynamic_slice(
+        act, (0, b * block), (q, block)))(blk)           # (n_blk, Q, block)
+    cols = jnp.where(blk_ok[:, None, None], cols, 0)
+    row_cum = jnp.cumsum(cols, axis=2, dtype=jnp.int32).reshape(-1)
+    row_cnt = jnp.transpose(cols.sum(axis=2, dtype=jnp.int32)).reshape(-1)
+    row_end = jnp.cumsum(row_cnt)                         # rows (q, k)
+    s = jnp.arange(n_slot, dtype=jnp.int32)
+    r = _search(row_end, s, 0, q * n_blk, _steps(q * n_blk))
+    slot_ok = r < q * n_blk
+    r = jnp.minimum(r, q * n_blk - 1)
+    slot_q, k = r // n_blk, r % n_blk
+    rank = s - (row_end[r] - row_cnt[r])
+    base = (k * q + slot_q) * block
+    j = _search(row_cum, rank, base, base + block, _steps(block)) - base
+    slot = jnp.where(slot_ok, blk[k] * block + j, 0)
+    slot_q = jnp.where(slot_ok, slot_q, q)
+    first = jnp.where(slot_ok, slot_start[slot], 0)
+    size = jnp.where(slot_ok, slot_start[slot + 1], 0) - first
+
+    n_ch = (size + chunk - 1) // chunk
+    ch_end = jnp.cumsum(n_ch)
+    c = jnp.arange(n_chunk, dtype=jnp.int32)
+    i = _search(ch_end, c, 0, n_slot, _steps(n_slot))    # c-th chunk's slot
+    i = jnp.minimum(i, n_slot - 1)
+    off = (c - (ch_end[i] - n_ch[i])) * chunk            # in its slot
+    lane = off[:, None] + jnp.arange(chunk)
+    lane_ok = (lane < size[i][:, None]) & (c < ch_end[-1])[:, None]
+    pos = jnp.where(lane_ok, first[i][:, None] + lane, 0).reshape(-1)
+
+    hit = lane_ok
+    confirm = None
+    if confirm_w:
+        box = queries[jnp.minimum(slot_q[i], q - 1)][:, None, :]
+        ov = _overlaps(
+            jnp.stack([x[pos].reshape(n_chunk, chunk) for x in mem_mbr], -1),
+            box)
+        tested = lane_ok & (size[i] > 1)[:, None]
+        hit = lane_ok & ~(tested & ~ov)
+        per_q = slot_q[i][:, None] == jnp.arange(q)
+        confirm = jnp.stack(
+            [jnp.where(per_q, x.sum(axis=1, dtype=jnp.int32)[:, None],
+                       0).sum(axis=0)
+             for x in (tested, tested & ov)], axis=1)
+    ids = jnp.where(hit.reshape(-1), mem_id[pos], -1)
+    q_chunks = jnp.where(slot_q[:, None] == jnp.arange(q), n_ch[:, None],
+                         0).sum(axis=0)
+    offsets = jnp.concatenate(
+        [jnp.zeros((1,), jnp.int32), jnp.cumsum(q_chunks) * chunk])
+    return confirm, offsets.astype(jnp.int32), ids
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=(
+        "n_objects", "block_w", "root_unconditional", "confirm_w",
+        "interpret", "caps", "stream", "win_w",
+    ),
+)
+def _fused_search_ids(
+    queries, members, mbr_cm, parent, obj_mbr, obj_level, obj_slot, obj_id,
+    n_shared=None,
+    *,
+    n_objects: int,
+    block_w: int,
+    root_unconditional: bool,
+    confirm_w: int,
+    interpret: bool,
+    caps: Tuple[int, int, int, int],
+    stream: bool = False,
+    win_off=None,
+    win_w: int | None = None,
+):
+    """Sweep + epilogue of a pyramid schedule (every entry at the deepest
+    level) that returns hit ids where they fit the capacities ``caps``
+    and the dense mask where they do not, in one program: ``(visits,
+    confirm, offsets, overflow, ids, hits)``.  Without ``overflow`` the
+    ids (:func:`_ids_epilogue`) hold the answer and ``hits`` is all
+    false; with it ``hits`` is :func:`_fused_search`'s and the ids are
+    empty.  ``visits`` and ``confirm`` are the same either way."""
+    act = level_sweep(
+        queries, mbr_cm, parent,
+        block_w=block_w,
+        root_unconditional=root_unconditional,
+        interpret=interpret,
+        stream=stream,
+        win_off=win_off,
+        win_w=win_w,
+        padded=True,
+    )  # (L, Q, Wp) int8
+    visits = jnp.transpose(act.sum(axis=2, dtype=jnp.int32))
+    q, w = queries.shape[0], mbr_cm.shape[2]
+    lanes = caps[2] * caps[3]
+
+    def from_ids(_):
+        confirm, offsets, ids = _ids_epilogue(
+            act[-1], queries, members, block=block_w, confirm_w=confirm_w,
+            caps=caps)
+        return confirm, offsets, ids, jnp.zeros((q, max(n_objects, 1)),
+                                                jnp.bool_)
+
+    def from_mask(_):
+        hits, _, confirm = _hits_epilogue(
+            act[:, :, :w] != 0, queries, None, obj_level, obj_slot, obj_id,
+            n_objects, shared_mbr=obj_mbr[:confirm_w] if confirm_w else None,
+            n_shared=n_shared)
+        return (confirm, jnp.zeros((q + 1,), jnp.int32),
+                jnp.full((lanes,), -1, jnp.int32), hits)
+
+    fit = _ids_fit(act[-1], members[0], block=block_w, caps=caps)
+    confirm, offsets, ids, hits = jax.lax.cond(fit, from_ids, from_mask, None)
+    return visits, confirm, offsets, ~fit, ids, hits
+
+
+@jax.jit
+def _member_table(mbr_last, obj_mbr, obj_slot, obj_id, n_shared):
+    """A pyramid's member table, built on the device (DESIGN.md §12).
+
+    Returns ``(ok, (slot_start, ids, lx, ly, hx, hy))``: ``slot_start``
+    (W + 1,) is the offset of each deepest slot's first member, then the
+    ids and the four MBR coordinates of the entries stably sorted by
+    slot, each (E,).  ``ok`` says whether the table answers as the dense
+    epilogue does: the ``n_shared`` first entries exactly those whose
+    slot holds another, and each other slot's box (``mbr_last``, (4, W))
+    its one entry's box, so that its group test is the object test."""
+    width = mbr_last.shape[1]
+    order = jnp.argsort(obj_slot, stable=True)
+    slot_start = jnp.searchsorted(
+        obj_slot[order], jnp.arange(width + 1, dtype=obj_slot.dtype),
+        side="left", method="sort").astype(jnp.int32)
+    alone = (slot_start[1:] - slot_start[:-1])[obj_slot] == 1
+    ok = jnp.all(alone == (jnp.arange(obj_slot.shape[0]) >= n_shared))
+    ok &= jnp.all(~alone[:, None] | (mbr_last[:, obj_slot].T == obj_mbr))
+    mbr = obj_mbr[order]
+    return ok, (slot_start, obj_id[order], *(mbr[:, c] for c in range(4)))
+
+
 @dataclasses.dataclass(frozen=True, eq=False)
 class StagedSchedule:
     """A schedule with its arrays on the device (DESIGN.md §12).
@@ -777,9 +1007,10 @@ class StagedSchedule:
     ``root_unconditional``, ``test_object_mbr`` or the grid's cells).
     ``source`` is the host schedule it was staged from: the streamed
     sweep's parent windows are planned from its parents once per tile
-    width (:meth:`windows`), and the eager launch report reads it.  A
-    holder that keeps this form across launches copies only its queries
-    to the device per launch.
+    width (:meth:`windows`), a pyramid's member table once
+    (:meth:`members`), and the eager launch report reads it.  A holder
+    that keeps this form across launches copies only its queries to the
+    device per launch.
     """
 
     source: LevelSchedule | QuantizedSchedule
@@ -787,6 +1018,8 @@ class StagedSchedule:
     arrays: Tuple[jax.Array, ...]
     statics: dict
     _windows: dict = dataclasses.field(default_factory=dict, repr=False)
+    _members: Tuple[jax.Array, ...] | bool | None = dataclasses.field(
+        default=None, repr=False)
 
     def windows(self, block_w: int) -> Tuple[jax.Array, int]:
         """``(win_off on the device, win_w)`` of :func:`parent_windows`
@@ -801,6 +1034,26 @@ class StagedSchedule:
             win_off, win_w = parent_windows(parent, n_real, block_w=block_w)
             plan = self._windows[block_w] = (to_device(win_off), win_w)
         return plan
+
+    def members(self) -> Tuple[jax.Array, ...] | None:
+        """The member table of :func:`_member_table`, built on the device
+        on the first call, for a pyramid whose table answers as its dense
+        epilogue does, and which can therefore return hit ids
+        (:func:`scan_staged_ids`, DESIGN.md §12): float32, every entry at
+        the deepest level, no object test of its own, the shared entries
+        first.  None for any other schedule."""
+        if self._members is None:
+            src, table = self.source, False
+            if (self.precision == "float32" and not src.test_object_mbr
+                    and np.all(np.asarray(src.obj_level) == src.levels - 1)):
+                mbr_cm, _, obj_mbr, _, obj_slot, obj_id = self.arrays[:6]
+                with _obs_trace.stage("engine.prepare", "prepare_s"):
+                    ok, cols = _member_table(mbr_cm[-1], obj_mbr, obj_slot,
+                                             obj_id, np.int32(src.n_shared))
+                    if jax.device_get(ok):
+                        table = cols
+            object.__setattr__(self, "_members", table)
+        return self._members or None
 
 
 def stage_schedule(schedule, precision: str = "float32") -> StagedSchedule:
@@ -908,21 +1161,12 @@ def scan_staged(
     """
     precision = staged.precision
     with _obs_trace.stage("engine.prepare", "prepare_s"):
-        win_off, win_w = staged.windows(block_w) if stream else (None, None)
-        if _obs_counters.collecting():  # side channel: eager wrappers only
-            _obs_counters.emit(_launch_report(
-                staged, queries, block_w=block_w, stream=stream,
-                win_off=win_off, win_w=win_w))
-        pad = 0 if pad_to is None else max(pad_to - queries.shape[0], 0)
-        if pad:
-            queries = np.concatenate([np.asarray(queries, np.float32),
-                                      np.broadcast_to(NEVER_MBR, (pad, 4))])
+        queries, kwargs = _launch_inputs(staged, queries, block_w=block_w,
+                                         stream=stream, pad_to=pad_to)
         run = {"float32": _fused_search, "compact": _fused_search_compact,
                "compact8": _fused_search_compact8}[precision]
-        kwargs = {} if precision == "compact8" else dict(
-            stream=stream, win_off=win_off, win_w=win_w)
         out = run(
-            to_device(queries, jnp.float32),
+            queries,
             *staged.arrays,
             **staged.statics,
             block_w=block_w,
@@ -930,6 +1174,62 @@ def scan_staged(
             **kwargs,
         )
     return out if precision == "float32" else (*out, None)
+
+
+def _launch_inputs(staged, queries, *, block_w, stream, pad_to):
+    """The ``engine.prepare`` work shared by every launch over a staged
+    schedule: the parent windows, the eager launch report, the queries
+    padded to ``pad_to`` and staged.  Returns ``(queries on the device,
+    the sweep's keyword arguments)``."""
+    win_off, win_w = staged.windows(block_w) if stream else (None, None)
+    if _obs_counters.collecting():  # side channel: eager wrappers only
+        _obs_counters.emit(_launch_report(
+            staged, queries, block_w=block_w, stream=stream,
+            win_off=win_off, win_w=win_w))
+    pad = 0 if pad_to is None else max(pad_to - queries.shape[0], 0)
+    if pad:
+        queries = np.concatenate([np.asarray(queries, np.float32),
+                                  np.broadcast_to(NEVER_MBR, (pad, 4))])
+    kwargs = {} if staged.precision == "compact8" else dict(
+        stream=stream, win_off=win_off, win_w=win_w)
+    return to_device(queries, jnp.float32), kwargs
+
+
+def scan_staged_ids(
+    staged: StagedSchedule,
+    queries,
+    *,
+    block_w: int = 128,
+    interpret: bool = False,
+    stream: bool = False,
+    pad_to: int | None = None,
+    caps: Tuple[int, int, int, int] | None = None,
+):
+    """One launch over a pyramid's :class:`StagedSchedule` (one whose
+    :meth:`~StagedSchedule.members` is not None) that returns hit ids in
+    place of the dense mask where they fit (DESIGN.md §12): device
+    ``(visits, confirm, offsets, overflow, ids, hits)``.  Query ``q``'s
+    id lanes are ``ids[offsets[q]:offsets[q + 1]]``, -1 where a lane
+    holds no hit.  Where the launch needs more than a capacity of
+    ``caps`` (default :func:`ids_caps`) ``overflow`` is set, the ids are
+    empty and ``hits`` holds :func:`scan_staged`'s dense mask, else it is
+    all false.  ``visits`` and ``confirm`` are :func:`scan_staged`'s.
+    Padding as in :func:`scan_staged`."""
+    with _obs_trace.stage("engine.prepare", "prepare_s"):
+        queries, kwargs = _launch_inputs(staged, queries, block_w=block_w,
+                                         stream=stream, pad_to=pad_to)
+        statics = dict(staged.statics)
+        del statics["test_object_mbr"]
+        return _fused_search_ids(
+            queries,
+            staged.members(),
+            *staged.arrays,
+            **statics,
+            block_w=block_w,
+            interpret=interpret,
+            caps=caps or ids_caps(staged, queries.shape[0], block_w),
+            **kwargs,
+        )
 
 
 def pyramid_scan(

@@ -101,6 +101,28 @@ def _stream_padded(s):
     )
 
 
+def _stream_ids(s):
+    """The pallas adapter's launch of a full block over the bit layer's
+    pyramid, returning hit ids where they fit (DESIGN.md §12): the
+    streamed sweep, the fit counts, the id epilogue's compactions with
+    the object test, and the dense epilogue it falls back to."""
+    n, levels, block_w = 4_000_000, 11, 512
+    members = (_spec(s, (n + 1,), jnp.int32), _spec(s, (n,), jnp.int32),
+               *(_spec(s, (n,), jnp.float32) for _ in range(4)))
+    return ops.fused_search_ids.lower(
+        _spec(s, (Q, 4), jnp.float32), members,
+        _spec(s, (levels, 4, n), jnp.float32),
+        _spec(s, (levels, n), jnp.int32),
+        _spec(s, (n, 4), jnp.float32),
+        *(_spec(s, (n,), jnp.int32) for _ in range(3)),
+        _spec(s, (), jnp.int32),
+        n_objects=n, block_w=block_w, root_unconditional=False,
+        confirm_w=3_932_160, interpret=False, caps=(256, 8192, 16, 32768),
+        stream=True, win_off=_spec(s, (levels, -(-n // block_w)), jnp.int32),
+        win_w=1024,
+    )
+
+
 def _hier(s):
     return ops.level_sweep_hier.lower(
         _spec(s, (Q, 4), jnp.int32), _spec(s, (Q, 4), jnp.int32),
@@ -136,6 +158,7 @@ KERNELS = {
     "resident_u16": lambda s: _resident(s, jnp.uint16, jnp.int32),
     "stream_1e7": _stream,
     "stream_padded_q3_4e6": _stream_padded,
+    "stream_ids_4e6": _stream_ids,
     "hier_u8_u16": _hier,
     "pair_2048x2048": _pair,
     "quantize": _quantize,
