@@ -1,4 +1,4 @@
-"""TPU-adaptation benchmarks: vectorized search, kernels, mqr-KV serving.
+"""TPU-adaptation benchmarks: vectorized search, kernels, spatial serving.
 
 ``REPRO_BENCH_TINY=1`` shrinks every object count to smoke sizes so the
 CI bench-smoke job can exercise the whole harness in seconds.
@@ -14,7 +14,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import datasets, flat, kvindex, mqrtree
+from repro.core import datasets, flat, mqrtree
 from repro.kernels import ops
 from repro.obs import counters as obs_counters
 from repro.obs import trace as obs_trace
@@ -358,38 +358,6 @@ def bench_durability():
     finally:
         shutil.rmtree(root, ignore_errors=True)
     return rows
-
-
-def bench_mqr_sparse_vs_dense_decode():
-    """The paper's payoff on the KV cache: pruned vs full decode attention."""
-    key = jax.random.PRNGKey(0)
-    s, d, bs, k = (2048, 64, 128, 4) if TINY else (16384, 64, 128, 16)
-    nb = s // bs
-    keys = jax.random.normal(key, (s, d))
-    vals = jax.random.normal(jax.random.fold_in(key, 1), (s, d))
-    probe = jax.random.normal(jax.random.fold_in(key, 2), (d,))
-    q = jax.random.normal(jax.random.fold_in(key, 3), (d,))
-
-    @jax.jit
-    def dense(q, keys, vals):
-        logits = keys @ q / jnp.sqrt(d)
-        return jax.nn.softmax(logits) @ vals
-
-    @jax.jit
-    def sparse(q, keys, vals):
-        idx = kvindex.build_kv_index(keys, probe, bs, 6)
-        ids = kvindex.select_blocks(idx, kvindex.query_region(q, probe, s), k)
-        kb = keys.reshape(nb, bs, d)[ids].reshape(-1, d)
-        vb = vals.reshape(nb, bs, d)[ids].reshape(-1, d)
-        logits = kb @ q / jnp.sqrt(d)
-        return jax.nn.softmax(logits) @ vb
-
-    t_d = _timeit(dense, q, keys, vals)
-    t_s = _timeit(sparse, q, keys, vals)
-    return [
-        (t_d, {"impl": "dense-decode", "kv": s}),
-        (t_s, {"impl": "mqr-sparse-decode", "kv": s, "blocks": f"{k}/{nb}"}),
-    ]
 
 
 def bench_join():
@@ -750,5 +718,4 @@ JAX_BENCHES = {
     "join": bench_join,
     "moving": bench_moving,
     "serving": bench_serving,
-    "mqr_sparse_vs_dense_decode": bench_mqr_sparse_vs_dense_decode,
 }

@@ -1,4 +1,3 @@
-from .checkpoint import CheckpointManager
 from .durable import DurableIndex, MutationResult, live_ids, mutation_workload
 from .spatial import (
     FORMAT_VERSION,
@@ -9,7 +8,6 @@ from .spatial import (
 )
 
 __all__ = [
-    "CheckpointManager",
     "DurableIndex",
     "MutationResult",
     "live_ids",

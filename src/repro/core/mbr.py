@@ -10,6 +10,10 @@ Definitions used by the paper's evaluation (Section 5.2):
                 the union of its entries' MBRs, summed over nodes.
   overlap       For each node, the total pairwise intersection area between
                 the MBRs of its entries, summed over nodes.
+
+The input hardening that every layer above shares lives here too:
+:func:`validate_mbrs` for built and inserted MBRs, :func:`validate_queries`
+for query rectangles.
 """
 
 from __future__ import annotations
@@ -28,6 +32,9 @@ __all__ = [
     "contains_point",
     "union_area",
     "pairwise_overlap_total",
+    "InvalidQueryError",
+    "validate_mbrs",
+    "validate_queries",
 ]
 
 LX, LY, HX, HY = 0, 1, 2, 3
@@ -174,3 +181,53 @@ def pairwise_overlap_total(mbrs: np.ndarray) -> float:
     inter = intersection_area(mbrs[:, None, :], mbrs[None, :, :])
     iu = np.triu_indices(n, k=1)
     return float(inter[iu].sum())
+
+
+class InvalidQueryError(ValueError):
+    """A query rectangle/point rejected at the serving boundary —
+    NaN/±inf coordinates or an inverted rectangle (DESIGN.md §11).
+    Typed so the front end can refuse one bad arrival without poisoning
+    the coalesced batch it would have joined."""
+
+
+def validate_queries(queries, *, what: str = "queries") -> np.ndarray:
+    """Boundary hardening for QUERY rectangles: same finite/non-inverted
+    rules as :func:`validate_mbrs`, but raising the typed
+    :class:`InvalidQueryError` and returning the kernels' (Q, 4) float32
+    form.  Degenerate-but-valid points (lo == hi) pass."""
+    try:
+        arr = validate_mbrs(queries, what=what)
+    except ValueError as e:
+        raise InvalidQueryError(str(e)) from None
+    return np.ascontiguousarray(arr, np.float32)
+
+
+def validate_mbrs(mbrs, *, what: str = "mbrs") -> np.ndarray:
+    """Input hardening shared by build and insert (DESIGN.md §9).
+
+    Rejects NaN / ±inf coordinates and inverted rectangles (lo > hi on
+    either axis) with a clear ``ValueError`` — degenerate geometry would
+    otherwise flow silently through every comparison-based sweep and
+    poison hit sets, quantized tiles, and the WAL.  Degenerate-but-valid
+    points (lo == hi) pass.  Returns the validated (n, 4) float64 array.
+    """
+    arr = np.asarray(mbrs, np.float64)
+    if arr.size % 4 != 0:
+        raise ValueError(
+            f"{what} must be (n, 4) [xlo, ylo, xhi, yhi]; got shape "
+            f"{arr.shape}"
+        )
+    arr = arr.reshape(-1, 4)
+    if not np.isfinite(arr).all():
+        bad = int(np.nonzero(~np.isfinite(arr).all(axis=1))[0][0])
+        raise ValueError(
+            f"{what}[{bad}] has a non-finite coordinate "
+            f"({arr[bad].tolist()}); NaN/±inf MBRs are rejected"
+        )
+    inverted = (arr[:, 0] > arr[:, 2]) | (arr[:, 1] > arr[:, 3])
+    if inverted.any():
+        bad = int(np.nonzero(inverted)[0][0])
+        raise ValueError(
+            f"{what}[{bad}] is inverted (lo > hi): {arr[bad].tolist()}"
+        )
+    return arr

@@ -1,7 +1,4 @@
-from .straggler import StragglerMonitor, StragglerEvent  # noqa: F401
-from .elastic import plan_mesh, build_mesh, reshard_plan, MeshPlan  # noqa: F401
 from .failures import (  # noqa: F401
-    FailureInjector,
     FaultPlan,
     InjectedFailure,
     KillPoint,

@@ -1,13 +1,9 @@
 """Failure injection for fault-tolerance tests.
 
-Two generations of harness live here:
-
-* :class:`FailureInjector` — the original train-loop hook: deterministic
-  or random crashes at step boundaries (``maybe_fail(step)``).
-* :class:`FaultPlan` — the reusable spatial-serving harness (DESIGN.md
-  §9).  One plan threads through the durable index, the write-ahead log,
-  the update engine's merge, and the spatial server's dispatch loop, so a
-  single object scripts *where* in the op/launch timeline a fault lands:
+:class:`FaultPlan` is the spatial-serving harness (DESIGN.md §9).  One
+plan threads through the durable index, the write-ahead log, the update
+engine's merge, and the spatial server's dispatch loop, so a single
+object scripts *where* in the op/launch timeline a fault lands:
 
     - ``kill_at_op`` / ``kill_site``: simulate a process kill at op ``k``,
       at the ``pre-append`` / ``post-append`` / ``post-apply`` WAL
@@ -29,8 +25,6 @@ from __future__ import annotations
 import dataclasses
 import time
 from typing import Optional, Tuple
-
-import numpy as np
 
 
 class InjectedFailure(RuntimeError):
@@ -132,22 +126,3 @@ class FaultPlan:
             self.fail_launches -= 1
             self.launch_failures += 1
             raise InjectedFailure(f"injected launch failure on rung {rung!r}")
-
-
-class FailureInjector:
-    def __init__(self, fail_at_step: Optional[int] = None,
-                 fail_prob: float = 0.0, seed: int = 0, max_failures: int = 1):
-        self.fail_at_step = fail_at_step
-        self.fail_prob = fail_prob
-        self.rng = np.random.default_rng(seed)
-        self.remaining = max_failures
-
-    def maybe_fail(self, step: int) -> None:
-        if self.remaining <= 0:
-            return
-        hit = (self.fail_at_step is not None and step == self.fail_at_step) or (
-            self.fail_prob > 0 and self.rng.random() < self.fail_prob
-        )
-        if hit:
-            self.remaining -= 1
-            raise InjectedFailure(f"injected node failure at step {step}")

@@ -46,6 +46,9 @@ import numpy as np
 
 from repro.core import bulk, mqrtree, rtree
 from repro.core.flat import FlatTree, LevelSchedule, flatten, level_schedule, pyramid_schedule
+from repro.core.mbr import InvalidQueryError as InvalidQueryError  # noqa: F401 (re-export)
+from repro.core.mbr import validate_mbrs
+from repro.core.mbr import validate_queries as validate_queries  # noqa: F401 (re-export)
 from repro.obs import counters as _obs_counters
 from repro.obs import trace as _obs_trace
 
@@ -61,56 +64,6 @@ _UPDATE_OPTS = ("capacity", "merge", "admission", "fault_plan")
 
 # Admission policies for mutations that cannot be buffered (DESIGN.md §9).
 ADMISSION_MODES = ("merge", "shed")
-
-
-class InvalidQueryError(ValueError):
-    """A query rectangle/point rejected at the serving boundary —
-    NaN/±inf coordinates or an inverted rectangle (DESIGN.md §11).
-    Typed so the front end can refuse one bad arrival without poisoning
-    the coalesced batch it would have joined."""
-
-
-def validate_queries(queries, *, what: str = "queries") -> np.ndarray:
-    """Boundary hardening for QUERY rectangles: same finite/non-inverted
-    rules as :func:`validate_mbrs`, but raising the typed
-    :class:`InvalidQueryError` and returning the kernels' (Q, 4) float32
-    form.  Degenerate-but-valid points (lo == hi) pass."""
-    try:
-        arr = validate_mbrs(queries, what=what)
-    except ValueError as e:
-        raise InvalidQueryError(str(e)) from None
-    return np.ascontiguousarray(arr, np.float32)
-
-
-def validate_mbrs(mbrs, *, what: str = "mbrs") -> np.ndarray:
-    """Input hardening shared by build and insert (DESIGN.md §9).
-
-    Rejects NaN / ±inf coordinates and inverted rectangles (lo > hi on
-    either axis) with a clear ``ValueError`` — degenerate geometry would
-    otherwise flow silently through every comparison-based sweep and
-    poison hit sets, quantized tiles, and the WAL.  Degenerate-but-valid
-    points (lo == hi) pass.  Returns the validated (n, 4) float64 array.
-    """
-    arr = np.asarray(mbrs, np.float64)
-    if arr.size % 4 != 0:
-        raise ValueError(
-            f"{what} must be (n, 4) [xlo, ylo, xhi, yhi]; got shape "
-            f"{arr.shape}"
-        )
-    arr = arr.reshape(-1, 4)
-    if not np.isfinite(arr).all():
-        bad = int(np.nonzero(~np.isfinite(arr).all(axis=1))[0][0])
-        raise ValueError(
-            f"{what}[{bad}] has a non-finite coordinate "
-            f"({arr[bad].tolist()}); NaN/±inf MBRs are rejected"
-        )
-    inverted = (arr[:, 0] > arr[:, 2]) | (arr[:, 1] > arr[:, 3])
-    if inverted.any():
-        bad = int(np.nonzero(inverted)[0][0])
-        raise ValueError(
-            f"{what}[{bad}] is inverted (lo > hi): {arr[bad].tolist()}"
-        )
-    return arr
 
 
 # ---------------------------------------------------------------------------

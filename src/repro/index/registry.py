@@ -1,11 +1,11 @@
 """Backend registry for the :class:`SpatialIndex` façade.
 
-Mirrors the ``configs/registry.py`` idiom: backends self-register with a
-declaration of (a) which structures they serve and (b) which build
-artifact they lower — the pointer tree, the ``FlatTree``, or the
-``LevelSchedule``.  The façade consults :func:`get_backend` at build time
-and :func:`advertised_pairs` is the single source of truth the parity
-matrix test sweeps (tests/test_index_api.py).
+Backends self-register with a declaration of (a) which structures they
+serve and (b) which build artifact they lower — the pointer tree, the
+``FlatTree``, or the ``LevelSchedule``.  The façade consults
+:func:`get_backend` at build time and :func:`advertised_pairs` is the
+single source of truth the parity matrix test sweeps
+(tests/test_index_api.py).
 
 Backends that accept ``precision="compact"`` (pallas, serve) additionally
 pull the QUANTIZED lowering — the conservative uint16 tile form of the

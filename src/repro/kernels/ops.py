@@ -18,11 +18,9 @@ from .build import build_levels_pallas as build_levels_pallas  # noqa: F401
 from .build import device_schedule as _device_schedule
 from .build import hilbert_keys as hilbert_keys  # noqa: F401 (re-export)
 from .build import hilbert_permute as hilbert_permute  # noqa: F401
-from .flash_attention import flash_attention as _flash
 from .join_scan import _fused_join
 from .join_scan import pair_sweep as _pair_sweep
 from .mbr_scan import mbr_scan as _mbr_scan
-from .mqr_sparse_attention import mqr_sparse_attention as _sparse
 from .pyramid_scan import (
     _fused_search,
     _fused_search_compact,
@@ -46,7 +44,6 @@ from .quantize import grid_params as grid_params  # noqa: F401 (re-export)
 from .quantize import quantize_cm_pallas as quantize_cm_pallas  # noqa: F401
 from .quantize import quantize_rows as quantize_rows  # noqa: F401 (re-export)
 from .quantize import quantize_schedule as _quantize_schedule
-from .rmsnorm import rmsnorm as _rmsnorm
 from .transfer import count_confirm as count_confirm  # noqa: F401
 from .transfer import fetch as fetch  # noqa: F401 (re-export)
 from .transfer import to_device as to_device  # noqa: F401 (re-export)
@@ -429,24 +426,5 @@ def per_level_region_search(schedule, queries, *, block_w: int = 128):
     )
 
 
-def flash_attention(q, k, v, *, block_q: int = 128, block_k: int = 128):
-    """Causal flash attention, (BH, S, D). kv heads must be pre-broadcast."""
-    return _flash(q, k, v, block_q=block_q, block_k=block_k,
-                  interpret=_interpret())
-
-
-def mqr_sparse_attention(q, k_blocks, v_blocks, ids, pos):
-    """Block-table decode attention over mqr-selected blocks."""
-    return _sparse(q, k_blocks, v_blocks, ids, jnp.asarray(pos, jnp.int32),
-                   interpret=_interpret())
-
-
-def rmsnorm(x, scale, eps: float = 1e-6):
-    return _rmsnorm(x, scale, eps, interpret=_interpret())
-
-
-# re-export oracles for tests/benches
+# re-export the oracle for tests/benches
 mbr_scan_ref = ref.mbr_scan_ref
-flash_attention_ref = ref.flash_attention_ref
-mqr_sparse_attention_ref = ref.mqr_sparse_attention_ref
-rmsnorm_ref = ref.rmsnorm_ref

@@ -1,11 +1,8 @@
-"""Pure-jnp oracles for every Pallas kernel (the ground truth in tests)."""
+"""Pure-jnp oracle of the MBR scan kernel (the ground truth in tests)."""
 
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
-
-NEG_INF = -1e30
 
 
 def mbr_scan_ref(mbrs: jnp.ndarray, queries: jnp.ndarray) -> jnp.ndarray:
@@ -18,46 +15,3 @@ def mbr_scan_ref(mbrs: jnp.ndarray, queries: jnp.ndarray) -> jnp.ndarray:
         & (a[..., 1] <= b[..., 3])
         & (b[..., 1] <= a[..., 3])
     )
-
-
-def flash_attention_ref(
-    q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, causal: bool = True
-) -> jnp.ndarray:
-    """q/k/v: (BH, S, D) -> (BH, S, D), fp32 softmax."""
-    s = q.shape[1]
-    scale = 1.0 / jnp.sqrt(q.shape[-1]).astype(jnp.float32)
-    logits = jnp.einsum("bqd,bkd->bqk", q, k).astype(jnp.float32) * scale
-    if causal:
-        mask = jnp.tril(jnp.ones((s, s), bool))
-        logits = jnp.where(mask[None], logits, NEG_INF)
-    p = jax.nn.softmax(logits, axis=-1)
-    return jnp.einsum("bqk,bkd->bqd", p.astype(v.dtype), v).astype(q.dtype)
-
-
-def mqr_sparse_attention_ref(
-    q: jnp.ndarray,       # (BH, D)
-    k_blocks: jnp.ndarray,  # (BH, nb, bs, D)
-    v_blocks: jnp.ndarray,  # (BH, nb, bs, D)
-    ids: jnp.ndarray,       # (BH, K) int32 selected blocks
-    pos: jnp.ndarray,       # scalar int32 — causal limit (inclusive)
-) -> jnp.ndarray:
-    """Block-table decode attention -> (BH, D)."""
-    bs = k_blocks.shape[2]
-    scale = 1.0 / jnp.sqrt(q.shape[-1]).astype(jnp.float32)
-
-    def per(qh, kb, vb, ih):
-        kg = kb[ih]  # (K, bs, D)
-        vg = vb[ih]
-        logits = jnp.einsum("d,ksd->ks", qh, kg).astype(jnp.float32) * scale
-        kpos = ih[:, None] * bs + jnp.arange(bs)[None, :]
-        logits = jnp.where(kpos <= pos, logits, NEG_INF)
-        p = jax.nn.softmax(logits.reshape(-1))
-        return jnp.einsum("k,kd->d", p.astype(vg.dtype), vg.reshape(-1, vg.shape[-1]))
-
-    return jax.vmap(per)(q, k_blocks, v_blocks, ids).astype(q.dtype)
-
-
-def rmsnorm_ref(x: jnp.ndarray, scale: jnp.ndarray, eps: float = 1e-6) -> jnp.ndarray:
-    xf = x.astype(jnp.float32)
-    var = jnp.mean(xf * xf, axis=-1, keepdims=True)
-    return (xf * jax.lax.rsqrt(var + eps) * scale.astype(jnp.float32)).astype(x.dtype)

@@ -22,8 +22,6 @@ Where this sits in the serving stack (one entry point per layer):
 * :mod:`repro.serve` is the user-facing serving FRONT END — continuous
   batching of single arrivals, SLO admission control, the multi-tenant
   registry.  New serving features land there, on top of this engine.
-* :mod:`repro.launch.serve` is the UNRELATED transformer decode driver
-  (same repo, different paper track); it serves tokens, not rectangles.
 """
 
 from __future__ import annotations
@@ -42,6 +40,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.flat import NEVER_MBR, LevelSchedule
+from repro.core.mbr import validate_queries
 from repro.kernels import fallback, ops
 from repro.obs import trace as _obs_trace
 
@@ -338,17 +337,13 @@ class SpatialServer:
         result-transparent.
 
         The boundary is hardened: NaN/±inf/inverted rectangles raise the
-        typed :class:`repro.index.InvalidQueryError` BEFORE any of them
+        typed :class:`repro.core.mbr.InvalidQueryError` BEFORE any of them
         can be cached or poison a padded batch's neighbours.
 
         Validation, keys, the cache lookup, dedupe and padding are the
         ``engine.prepare`` stage; the cache fill and unstacking after the
         fetch are ``engine.finish``.
         """
-        # lazy import: repro.index imports this module's backend wrapper,
-        # so the validation helper is pulled at call time, not import time
-        from repro.index.api import validate_queries
-
         with _obs_trace.stage("engine.prepare", "prepare_s"):
             queries = validate_queries(queries, what="served queries")
             nq = queries.shape[0]

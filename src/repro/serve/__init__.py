@@ -13,7 +13,6 @@ THE documented serving entry point for spatial queries.  The layering is:
   deadlines that sheds or queues under overload, a declarative
   multi-tenant registry (config → built stack), and streaming latency
   telemetry (p50/p99/p99.9 histograms).
-* :mod:`repro.launch.serve` — unrelated: the LM token-decoding driver.
 
 Every answer served through the queue is bit-identical to calling the
 tenant's :class:`repro.index.SpatialIndex` directly

@@ -1,4 +1,4 @@
-"""Pallas kernels vs pure-jnp oracles: shape/dtype sweeps (interpret mode)."""
+"""The MBR scan kernel vs its pure-jnp oracle: shape sweeps (interpret mode)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -20,75 +20,3 @@ def test_mbr_scan_sweep(n, q):
     want = ops.mbr_scan_ref(mbrs, qs)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
-
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("bh,s,d", [(2, 128, 64), (4, 256, 128), (1, 384, 128)])
-def test_flash_attention_sweep(dtype, bh, s, d):
-    key = jax.random.PRNGKey(bh * s + d)
-    q = jax.random.normal(key, (bh, s, d), dtype)
-    k = jax.random.normal(jax.random.fold_in(key, 1), (bh, s, d), dtype)
-    v = jax.random.normal(jax.random.fold_in(key, 2), (bh, s, d), dtype)
-    got = ops.flash_attention(q, k, v, block_q=128, block_k=128)
-    want = ops.flash_attention_ref(q, k, v)
-    tol = 2e-2 if dtype == jnp.bfloat16 else 2e-3
-    np.testing.assert_allclose(
-        np.asarray(got, np.float32), np.asarray(want, np.float32), atol=tol, rtol=tol
-    )
-
-
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("bh,nb,bs,d,k", [(2, 8, 128, 64, 3), (4, 16, 128, 128, 8)])
-def test_mqr_sparse_attention_sweep(dtype, bh, nb, bs, d, k):
-    key = jax.random.PRNGKey(nb * bs + d)
-    kb = jax.random.normal(key, (bh, nb, bs, d), dtype)
-    vb = jax.random.normal(jax.random.fold_in(key, 1), (bh, nb, bs, d), dtype)
-    q = jax.random.normal(jax.random.fold_in(key, 2), (bh, d), dtype)
-    ids = jnp.stack(
-        [
-            jax.random.permutation(jax.random.fold_in(key, 3 + i), nb)[:k]
-            for i in range(bh)
-        ]
-    ).astype(jnp.int32)
-    pos = jnp.asarray(nb * bs // 2, jnp.int32)
-    got = ops.mqr_sparse_attention(q, kb, vb, ids, pos)
-    want = ops.mqr_sparse_attention_ref(q, kb, vb, ids, pos)
-    tol = 3e-2 if dtype == jnp.bfloat16 else 2e-3
-    np.testing.assert_allclose(
-        np.asarray(got, np.float32), np.asarray(want, np.float32), atol=tol, rtol=tol
-    )
-
-
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("r,d", [(64, 128), (300, 256), (1, 512)])
-def test_rmsnorm_sweep(dtype, r, d):
-    key = jax.random.PRNGKey(r + d)
-    x = jax.random.normal(key, (r, d), dtype)
-    s = jax.random.normal(jax.random.fold_in(key, 1), (d,), jnp.float32)
-    got = ops.rmsnorm(x, s)
-    want = ops.rmsnorm_ref(x, s)
-    tol = 5e-2 if dtype == jnp.bfloat16 else 1e-5
-    np.testing.assert_allclose(
-        np.asarray(got, np.float32), np.asarray(want, np.float32), atol=tol, rtol=tol
-    )
-
-
-def test_flash_matches_model_attention_path():
-    """The Pallas kernel and the model's portable flash path agree."""
-    from repro.models.attention import flash_attention_jnp
-
-    key = jax.random.PRNGKey(0)
-    b, s, h, dh = 2, 256, 4, 64
-    q = jax.random.normal(key, (b, s, h, dh), jnp.float32)
-    k = jax.random.normal(jax.random.fold_in(key, 1), (b, s, h, dh), jnp.float32)
-    v = jax.random.normal(jax.random.fold_in(key, 2), (b, s, h, dh), jnp.float32)
-    positions = jnp.broadcast_to(jnp.arange(s), (b, s))
-    want = flash_attention_jnp(q, k, v, positions, positions, chunk=128)
-    got = ops.flash_attention(
-        jnp.moveaxis(q, 2, 1).reshape(b * h, s, dh),
-        jnp.moveaxis(k, 2, 1).reshape(b * h, s, dh),
-        jnp.moveaxis(v, 2, 1).reshape(b * h, s, dh),
-    ).reshape(b, h, s, dh)
-    got = jnp.moveaxis(got, 1, 2)
-    np.testing.assert_allclose(
-        np.asarray(got), np.asarray(want), atol=2e-3, rtol=2e-3
-    )
